@@ -52,10 +52,10 @@ from dataclasses import dataclass, replace
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
-import jax
 
 from repro.core import floatbits as fb
 from .audit import _eqn_frames
+from .jaxpr_types import ClosedJaxpr, is_drop_var, is_literal
 from .domains import (
     AbsVal, BIG, DEFAULT_WIDTHS, EPS_EXP2_MEAN, EPS_EXP2_WORST,
     EPS_LOG2_ABS_MEAN, EPS_LOG2_ABS_WORST, EPS_PADIV_MEAN, EPS_PADIV_WORST,
@@ -249,7 +249,7 @@ class Interp:
 
     # -- env --------------------------------------------------------------
     def read(self, atom):
-        if isinstance(atom, jax.core.Literal):
+        if is_literal(atom):
             return val_of_array(atom.val, self.nw)
         v = self.env.get(atom)
         if v is None:
@@ -292,14 +292,14 @@ class Interp:
         for eqn in jaxpr.eqns:
             self.n_eqns += 1
             for ov in eqn.outvars:
-                if not isinstance(ov, jax.core.DropVar):
+                if not is_drop_var(ov):
                     self.defs[ov] = eqn
             self._eqn(eqn)
         return [self.read(v) for v in jaxpr.outvars]
 
     def _bind_outs(self, eqn, outs):
         for ov, val in zip(eqn.outvars, outs):
-            if not isinstance(ov, jax.core.DropVar):
+            if not is_drop_var(ov):
                 self.env[ov] = self._ceil_contract(val)
 
     def _ceil_contract(self, val):
@@ -347,7 +347,7 @@ class Interp:
                     # pass through join-only until the dance exits.
                     je = self._join_errs([self.read(v) for v in eqn.invars])
                     for ov in eqn.outvars:
-                        if isinstance(ov, jax.core.DropVar):
+                        if is_drop_var(ov):
                             continue
                         v = self.env.get(ov)
                         if v is not None:
@@ -367,8 +367,7 @@ class Interp:
 
     # -- central witness evaluation ---------------------------------------
     def _witness(self, eqn, name):
-        if len(eqn.outvars) != 1 or isinstance(eqn.outvars[0],
-                                               jax.core.DropVar):
+        if len(eqn.outvars) != 1 or is_drop_var(eqn.outvars[0]):
             return
         cur = self.env.get(eqn.outvars[0])
         if cur is None or cur.wit is not None:
@@ -392,9 +391,7 @@ class Interp:
                 axes, origin = w.axes, w.origin
         try:
             with np.errstate(all="ignore"):
-                args = [_np_of(iv.aval, v.wit.val) if not isinstance(
-                            iv, jax.core.Literal)
-                        else _np_of(iv.aval, v.wit.val)
+                args = [_np_of(iv.aval, v.wit.val)
                         for iv, v in zip(eqn.invars, vals)]
                 if name == "shift_right_logical":
                     out = _srl32(int(args[0]), int(args[1]))
@@ -427,7 +424,7 @@ class Interp:
 
     # -- def-chain resolution ---------------------------------------------
     def _resolve(self, atom):
-        if isinstance(atom, jax.core.Literal):
+        if is_literal(atom):
             return atom, None
         v = atom
         for _ in range(64):
@@ -439,11 +436,11 @@ class Interp:
             name = eqn.primitive.name
             if name in _RESOLVE_PASS:
                 iv = eqn.invars[0]
-                if isinstance(iv, jax.core.Literal):
+                if is_literal(iv):
                     return v, eqn
                 v = iv
                 continue
-            if name == "pjit":
+            if name in ("jit", "pjit"):
                 try:
                     idx = list(eqn.outvars).index(v)
                     v = eqn.params["jaxpr"].jaxpr.outvars[idx]
@@ -478,7 +475,7 @@ class Interp:
             return
         fin = None
         for iv in eqn.invars:
-            if isinstance(iv, jax.core.Literal):
+            if is_literal(iv):
                 continue            # clip bounds etc. are not the input
             aval = getattr(iv, "aval", None)
             if aval is not None and getattr(aval, "dtype", None) is not None \
@@ -491,7 +488,7 @@ class Interp:
         inj = self._inj_exp2(fin) if kind == "exp2" else self._inj_log2(fin)
         self._injected.add(key)
         for ov in eqn.outvars:
-            if isinstance(ov, jax.core.DropVar):
+            if is_drop_var(ov):
                 continue
             v = self.env.get(ov)
             if v is not None:
@@ -617,14 +614,13 @@ def _relmax_rule(it, eqn, xa):
     """sub(x, broadcast(reduce_max(x, axes))) -> [lo-hi, 0] with an
     attained-zero witness (the softmax shift)."""
     xatom, matom = eqn.invars
-    if isinstance(xatom, jax.core.Literal) \
-            or isinstance(matom, jax.core.Literal):
+    if is_literal(xatom) or is_literal(matom):
         return None
     mv, md = it._resolve(matom)
     if md is None or md.primitive.name != "reduce_max":
         return None
     op = md.invars[0]
-    if isinstance(op, jax.core.Literal):
+    if is_literal(op):
         return None
     ov, _ = it._resolve(op)
     xv, _ = it._resolve(xatom)
@@ -1089,7 +1085,7 @@ def _h_cmp(it, eqn):
     # the inf/nan edge selects for finite declared inputs.
     dec = None
     same = (len(eqn.invars) == 2
-            and not isinstance(eqn.invars[0], jax.core.Literal)
+            and not is_literal(eqn.invars[0])
             and eqn.invars[0] is eqn.invars[1])
     if same:
         # x == x: abstractly true — declared inputs carry no NaN and NaN
@@ -1128,7 +1124,7 @@ def _sel_false_lo(it, eqn):
         if pe is None or pe.primitive.name != "lt":
             return None
         u_atom, k_atom = pe.invars
-        if not isinstance(k_atom, jax.core.Literal):
+        if not is_literal(k_atom):
             return None
         karr = np.asarray(k_atom.val)
         if not np.issubdtype(karr.dtype, np.integer) or karr.size != 1:
@@ -1141,7 +1137,7 @@ def _sel_false_lo(it, eqn):
         if fe is not None and fe.primitive.name in ("min", "max"):
             lit, other = None, None
             for a in fe.invars:
-                if isinstance(a, jax.core.Literal):
+                if is_literal(a):
                     la = np.asarray(a.val)
                     if np.issubdtype(la.dtype, np.integer) and la.size == 1:
                         lit = int(la.reshape(()))
@@ -1422,7 +1418,7 @@ def _extrap_err(e_out: Err, e_in: Err, L: float, nw: int) -> Err:
 
 def _alias_call(it, body, eqn_invars):
     for bv, atom in zip(body.invars, eqn_invars):
-        if not isinstance(atom, jax.core.Literal):
+        if not is_literal(atom):
             it.alias[bv] = atom
 
 
@@ -1559,7 +1555,7 @@ def _h_custom_vjp(it, eqn):
 def _h_remat(it, eqn):
     body = eqn.params["jaxpr"]
     vals = _rd(it, eqn)
-    if isinstance(body, jax.core.ClosedJaxpr):
+    if isinstance(body, ClosedJaxpr):
         consts = [val_of_array(c, it.nw) for c in body.consts]
         body = body.jaxpr
     else:
@@ -1575,7 +1571,7 @@ def _h_remat(it, eqn):
 def _h_shard_map(it, eqn):
     body = eqn.params["jaxpr"]
     vals = _rd(it, eqn)
-    if isinstance(body, jax.core.ClosedJaxpr):
+    if isinstance(body, ClosedJaxpr):
         consts = [val_of_array(c, it.nw) for c in body.consts]
         body = body.jaxpr
     else:
@@ -1640,7 +1636,8 @@ _HANDLERS = {
     "all_gather": _h_identity, "ppermute": _h_identity,
     "axis_index": _h_axis_index,
     "scan": _h_scan, "while": _h_while, "cond": _h_cond,
-    "pjit": _h_pjit, "closed_call": _h_pjit, "core_call": _h_pjit,
+    "jit": _h_pjit, "pjit": _h_pjit, "closed_call": _h_pjit,
+    "core_call": _h_pjit,
     "custom_jvp_call": _h_custom_vjp,
     "custom_vjp_call": _h_custom_vjp,
     "custom_vjp_call_jaxpr": _h_custom_vjp,

@@ -36,7 +36,8 @@ from collections import defaultdict
 from typing import Dict, List, Tuple
 
 import numpy as np
-import jax
+
+from .jaxpr_types import is_literal, open_jaxpr, sub_jaxprs
 
 MUL_FAMILY = ("mul", "div", "pow", "integer_pow", "sqrt", "rsqrt", "square")
 # Contractions are multiplication work regardless of output shape (a dot
@@ -155,7 +156,7 @@ def _is_float_dtype(dtype) -> bool:
 
 
 def _is_pow2_scalar_literal(var) -> bool:
-    if not isinstance(var, jax.core.Literal):
+    if not is_literal(var):
         return False
     val = np.asarray(var.val)
     if val.size != 1 or not np.issubdtype(val.dtype, np.floating):
@@ -257,19 +258,12 @@ def jaxpr_mul_stats(jaxpr) -> Dict:
                 else:
                     record(eqn, name, aval, ctx)
             # Generic sub-jaxpr recursion: any equation param that is (or
-            # contains) a Jaxpr is walked under this equation's context.
-            # This covers scan, while (cond_jaxpr/body_jaxpr), cond
-            # (branches tuple), pjit, shard_map, remat2, custom_jvp/vjp
-            # and pallas_call on jax 0.4.x — verified in test_analysis.py.
-            for p in eqn.params.values():
-                for item in (p if isinstance(p, (tuple, list)) else (p,)):
-                    if isinstance(item, jax.core.ClosedJaxpr):
-                        walk(item.jaxpr, ctx + (name,))
-                    elif isinstance(item, jax.core.Jaxpr):
-                        walk(item, ctx + (name,))
+            # contains) a Jaxpr is walked under this equation's context —
+            # verified per primitive in test_analysis.py.
+            for sub in sub_jaxprs(eqn):
+                walk(sub, ctx + (name,))
 
-    walk(jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr,
-         ())
+    walk(open_jaxpr(jaxpr), ())
     sites = [f"{v.prim}@{v.site}" for v in violations]
     return {"tensor": dict(stats["tensor"]), "scalar": dict(stats["scalar"]),
             "pow2": stats["pow2"], "integer": stats["integer"],

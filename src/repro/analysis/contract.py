@@ -48,10 +48,10 @@ from collections import defaultdict
 from typing import Dict, Iterator, Tuple
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from .audit import _eqn_frames, _is_pow2_scalar_literal
+from .jaxpr_types import is_literal, open_jaxpr, sub_jaxprs
 
 # Both PAM operands must be able to reach ~2^64 for the product to cross
 # the 2^129 flush-to-zero wrap (DESIGN.md §2.3).
@@ -66,12 +66,8 @@ def _iter_eqns(jx, ctx: Tuple[str, ...] = ()) -> Iterator:
     for eqn in jx.eqns:
         yield eqn, ctx
         name = eqn.primitive.name
-        for p in eqn.params.values():
-            for item in (p if isinstance(p, (tuple, list)) else (p,)):
-                if isinstance(item, jax.core.ClosedJaxpr):
-                    yield from _iter_eqns(item.jaxpr, ctx + (name,))
-                elif isinstance(item, jax.core.Jaxpr):
-                    yield from _iter_eqns(item, ctx + (name,))
+        for sub in sub_jaxprs(eqn):
+            yield from _iter_eqns(sub, ctx + (name,))
 
 
 def _is_float_dtype(dtype) -> bool:
@@ -84,7 +80,7 @@ def _is_float_dtype(dtype) -> bool:
 def _scalar_float_literal(var):
     """The literal's python float if var is a finite scalar float literal,
     else None."""
-    if not isinstance(var, jax.core.Literal):
+    if not is_literal(var):
         return None
     val = np.asarray(var.val)
     if val.size != 1 or not np.issubdtype(val.dtype, np.floating):
@@ -111,7 +107,7 @@ def contract_lint(jaxpr) -> Dict:
         (errors if severity == "error" else warnings).append(
             _finding(rule, severity, eqn, ctx, detail))
 
-    root = jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr
+    root = open_jaxpr(jaxpr)
     for eqn, ctx in _iter_eqns(root):
         name = eqn.primitive.name
         out_aval = getattr(eqn.outvars[0], "aval", None) if eqn.outvars \

@@ -82,28 +82,40 @@ class FloatFormat:
     mantissa correction ``2^(man_bits - l(man_bits))`` added to the PAM
     magnitude sum (equivalently: a bias fold of ``BIAS_SHIFTED -
     LMUL_OFFSET``).
+
+    ``wide=True`` is the same layout carried in int32 instead: ``bits``
+    sign-extends the 16-bit pattern, ``floats`` truncates it back, and the
+    constants are the same signed values as int32. TPU kernels use it
+    because the v5e vector unit has no 16-bit integer compares or shifts
+    (and no bf16 compares); every carrier op that stays inside the int16
+    range gives the same result in int32, and the ops that wrap in int16
+    land on the same clamp or flush (DESIGN.md §11).
     """
 
     name: str
     width: int
     exp_bits: int
     man_bits: int
+    wide: bool = False
 
     def __post_init__(self):
         set_ = object.__setattr__
         if self.width == 32:
-            dtype, carrier, np_carrier = jnp.float32, jnp.int32, np.int32
+            dtype, storage = jnp.float32, jnp.int32
         elif self.width == 16 and self.exp_bits == 8:
-            dtype, carrier, np_carrier = jnp.bfloat16, jnp.int16, np.int16
+            dtype, storage = jnp.bfloat16, jnp.int16
         elif self.width == 16 and self.exp_bits == 5:
-            dtype, carrier, np_carrier = jnp.float16, jnp.int16, np.int16
+            dtype, storage = jnp.float16, jnp.int16
         else:
             raise ValueError(f"unsupported float layout: {self!r}")
+        carrier = jnp.int32 if self.wide else storage
+        np_carrier = np.dtype(carrier).type
         assert 1 + self.exp_bits + self.man_bits == self.width
         m, e = self.man_bits, self.exp_bits
         bias = (1 << (e - 1)) - 1
         set_(self, "exp_bias", bias)
         set_(self, "dtype", dtype)
+        set_(self, "storage", storage)
         set_(self, "carrier", carrier)
         set_(self, "np_carrier", np_carrier)
         set_(self, "SIGN_MASK", np_carrier(-(1 << (self.width - 1))))
@@ -120,12 +132,21 @@ class FloatFormat:
         set_(self, "LMUL_L", _lmul_l(m))
         set_(self, "LMUL_OFFSET", np_carrier(1 << (m - _lmul_l(m))))
 
+    @property
+    def widened(self) -> "FloatFormat":
+        """This layout on an int32 carrier (itself for 32-bit formats)."""
+        if self.width == 32 or self.wide:
+            return self
+        return _WIDENED[self.name]
+
 
 FLOAT32 = FloatFormat("f32", 32, 8, 23)
 BFLOAT16 = FloatFormat("bf16", 16, 8, 7)
 FLOAT16 = FloatFormat("f16", 16, 5, 10)
 
 FORMATS = {f.name: f for f in (FLOAT32, BFLOAT16, FLOAT16)}
+_WIDENED = {f.name: dataclasses.replace(f, wide=True)
+            for f in (BFLOAT16, FLOAT16)}
 
 # The refactor invariant: FLOAT32's derived fields ARE the historical
 # module constants (same np.int32 values the kernels close over).
@@ -151,12 +172,20 @@ def format_for_dtype(dtype) -> FloatFormat:
 
 def bits(x: jax.Array, fmt: FloatFormat = FLOAT32) -> jax.Array:
     """float -> carrier-int bit pattern (f32->int32 by default)."""
-    return jax.lax.bitcast_convert_type(x.astype(fmt.dtype), fmt.carrier)
+    i = jax.lax.bitcast_convert_type(x.astype(fmt.dtype), fmt.storage)
+    return i.astype(fmt.carrier) if fmt.wide else i
 
 
 def floats(i: jax.Array, fmt: FloatFormat = FLOAT32) -> jax.Array:
     """carrier-int bit pattern -> float (int32->f32 by default)."""
-    return jax.lax.bitcast_convert_type(i.astype(fmt.carrier), fmt.dtype)
+    return jax.lax.bitcast_convert_type(i.astype(fmt.storage), fmt.dtype)
+
+
+def cmp_view(x: jax.Array, fmt: FloatFormat) -> jax.Array:
+    """``x`` as float compares, floor and round should see it: itself, or
+    its exact f32 embedding for a widened narrow format (the TPU vector
+    unit compares and rounds only in f32)."""
+    return x.astype(jnp.float32) if fmt.wide else x
 
 
 def sign_bits(x: jax.Array, fmt: FloatFormat = FLOAT32) -> jax.Array:
@@ -221,7 +250,8 @@ def pow2_mul(x: jax.Array, k, fmt: FloatFormat | None = None) -> jax.Array:
                     jnp.minimum(mag, fmt.MAX_FINITE))
     out = floats(sign | mag, fmt)
     # preserve zeros / non-finite inputs
-    return jnp.where((x == 0) | ~jnp.isfinite(x), x, out)
+    xc = cmp_view(x, fmt)
+    return jnp.where((xc == 0) | ~jnp.isfinite(xc), x, out)
 
 
 def mantissa_round(x: jax.Array, keep_bits: int) -> jax.Array:

@@ -191,8 +191,10 @@ def _grouped_pam_sum(saT, amT, sb, bmg, bzM, g, fmt=fb.FLOAT32):
     partial sums accumulate in f32 (exact embedding for bf16/f16, a no-op
     on the f32 path).
 
-    NOTE: keep in sync with kernels/pam_matmul/kernel.py::_grouped_pam_sum
-    (same algorithm on the kernel's per-tile layout)."""
+    NOTE: keep the per-product bit algorithm in sync with the kernels'
+    tile product (kernels/pa_prims.py::_make_pam_dot); the kernels add
+    their group partials in contraction order, this engine tree-reduces
+    them, so sums agree to f32 rounding."""
     part = None
     for j in range(g):
         mag = amT[..., :, j, :, None] + bmg[..., :, j, None, :]

@@ -102,10 +102,11 @@ def _pam_like_value(a, b, fmt, fold):
     mag = jnp.where(ovf, fmt.MAX_FINITE, mag)
     out = fb.floats(sign | mag, fmt)
     zero = _value_zero(a, ai, fmt) | _value_zero(b, bi, fmt)
-    inf = jnp.isinf(a) | jnp.isinf(b)
+    ac, bc = fb.cmp_view(a, fmt), fb.cmp_view(b, fmt)
+    inf = jnp.isinf(ac) | jnp.isinf(bc)
     out = jnp.where(zero, fb.floats(sign, fmt), out)                # signed zero
     out = jnp.where(inf, fb.floats(sign | fmt.INF_BITS, fmt), out)  # signed inf
-    nan = jnp.isnan(a) | jnp.isnan(b) | (inf & zero)                # 0 * inf -> nan
+    nan = jnp.isnan(ac) | jnp.isnan(bc) | (inf & zero)              # 0 * inf -> nan
     return jnp.where(nan, jnp.asarray(jnp.nan, fmt.dtype), out)
 
 
@@ -128,7 +129,10 @@ def lmul_value(a, b):
 
 def padiv_value(a, b):
     """Bit-exact PA division: carrier magnitude subtract, re-bias, clamp."""
-    fmt = _operand_fmt(a, b)
+    return _padiv_value(a, b, _operand_fmt(a, b))
+
+
+def _padiv_value(a, b, fmt):
     a, b = jnp.asarray(a, fmt.dtype), jnp.asarray(b, fmt.dtype)
     ai, bi = fb.bits(a, fmt), fb.bits(b, fmt)
     sign = (ai ^ bi) & fmt.SIGN_MASK
@@ -140,31 +144,37 @@ def padiv_value(a, b):
     out = fb.floats(sign | mag, fmt)
     az = _value_zero(a, ai, fmt)
     bz = _value_zero(b, bi, fmt)
+    ac, bc = fb.cmp_view(a, fmt), fb.cmp_view(b, fmt)
     out = jnp.where(az, fb.floats(sign, fmt), out)                      # 0/b
     out = jnp.where(bz, fb.floats(sign | fmt.INF_BITS, fmt), out)       # a/0
-    out = jnp.where(jnp.isinf(a), fb.floats(sign | fmt.INF_BITS, fmt), out)
-    out = jnp.where(jnp.isinf(b), fb.floats(sign, fmt), out)            # a/inf
-    nan = (jnp.isnan(a) | jnp.isnan(b)
+    out = jnp.where(jnp.isinf(ac), fb.floats(sign | fmt.INF_BITS, fmt), out)
+    out = jnp.where(jnp.isinf(bc), fb.floats(sign, fmt), out)           # a/inf
+    nan = (jnp.isnan(ac) | jnp.isnan(bc)
            | (az & bz)
-           | (jnp.isinf(a) & jnp.isinf(b)))
+           | (jnp.isinf(ac) & jnp.isinf(bc)))
     return jnp.where(nan, jnp.asarray(jnp.nan, fmt.dtype), out)
 
 
 def paexp2_value(a):
     """paexp2(A) = 2^floor(A) * (1 + A - floor(A))   (paper Eq. 9)."""
-    fmt = _operand_fmt(a)
+    return _paexp2_value(a, _operand_fmt(a))
+
+
+def _paexp2_value(a, fmt):
     a = jnp.asarray(a, fmt.dtype)
     # Clamp the range used for bit manipulation: anything <= -150 underflows
     # to 0 and anything >= 128 overflows to inf regardless, and the clamp
     # keeps floor()/int conversion well-defined for +-inf / huge mask values.
     # (+-16384 = 2^14 is exact in every supported format.)
     ac = jnp.clip(a, -16384.0, 16384.0)
-    n = jnp.floor(ac)
+    n = jnp.floor(fb.cmp_view(ac, fmt)).astype(fmt.dtype)
     f = ac - n                                  # in [0, 1): pure float subtract
-    man = jnp.round(f * jnp.asarray(2.0**fmt.man_bits, fmt.dtype)).astype(fmt.carrier)
+    man = jnp.round(fb.cmp_view(f * jnp.asarray(2.0**fmt.man_bits, fmt.dtype),
+                                fmt)).astype(fmt.carrier)
     carry = man >> fmt.man_bits                 # f rounded up to exactly 1.0
     out = fb.compose(fmt.np_carrier(0), n.astype(fmt.carrier) + carry,
                      man & fmt.MAN_MASK, fmt)
+    a = fb.cmp_view(a, fmt)
     out = jnp.where(a >= 128.0, jnp.asarray(jnp.inf, fmt.dtype), out)
     return jnp.where(jnp.isnan(a), jnp.asarray(jnp.nan, fmt.dtype), out)
 
@@ -174,12 +184,16 @@ def palog2_value(a):
 
     Computed as (bits(A) - bits(1.0)) * 2^-man_bits — an int subtract and an
     exact power-of-two scale (multiplication-free)."""
-    fmt = _operand_fmt(a)
+    return _palog2_value(a, _operand_fmt(a))
+
+
+def _palog2_value(a, fmt):
     a = jnp.asarray(a, fmt.dtype)
     ai = fb.bits(a, fmt)
     out = ((ai - fmt.BIAS_SHIFTED).astype(fmt.dtype)
            * jnp.asarray(2.0**-fmt.man_bits, fmt.dtype))
     out = jnp.where(_value_zero(a, ai, fmt), -jnp.asarray(jnp.inf, fmt.dtype), out)
+    a = fb.cmp_view(a, fmt)
     out = jnp.where(a < 0, jnp.asarray(jnp.nan, fmt.dtype), out)
     return jnp.where(jnp.isnan(a), jnp.asarray(jnp.nan, fmt.dtype), out)
 
@@ -188,7 +202,42 @@ def pasqrt_value(a):
     """Value-level pasqrt(A) = paexp2(palog2(A) ÷ 2) (paper Eq. 20); the ÷2
     is an exact power-of-two exponent shift. Matches the ``pasqrt``
     custom-vjp op's forward bit for bit."""
-    return paexp2_value(fb.pow2_mul(palog2_value(a), -1))
+    return _pasqrt_value(a, _operand_fmt(a))
+
+
+def _pasqrt_value(a, fmt):
+    return _paexp2_value(fb.pow2_mul(_palog2_value(a, fmt), -1, fmt), fmt)
+
+
+class ValueOps:
+    """The value-level PA ops bound to one FloatFormat: the same functions
+    as ``pam_value`` & co. without the dtype dispatch. Kernels bind the
+    widened format (``fmt.widened``), whose results equal the dispatched
+    ops on the same inputs bit for bit. Binary operands broadcast to one
+    shape first (a TPU kernel bitcasts vectors, never scalars)."""
+
+    def __init__(self, fmt: fb.FloatFormat):
+        self.fmt = fmt
+
+    def _cast(self, a, b):
+        return jnp.broadcast_arrays(jnp.asarray(a, self.fmt.dtype),
+                                    jnp.asarray(b, self.fmt.dtype))
+
+    def pam(self, a, b):
+        a, b = self._cast(a, b)
+        return _pam_like_value(a, b, self.fmt, self.fmt.BIAS_SHIFTED)
+
+    def padiv(self, a, b):
+        return _padiv_value(*self._cast(a, b), self.fmt)
+
+    def paexp2(self, a):
+        return _paexp2_value(a, self.fmt)
+
+    def palog2(self, a):
+        return _palog2_value(a, self.fmt)
+
+    def pasqrt(self, a):
+        return _pasqrt_value(a, self.fmt)
 
 
 # -- Exact-derivative scale factors (all signed powers of two) --------------

@@ -16,7 +16,8 @@ flash_attention — online-softmax attention: the float kernel, plus the
 Each kernel ships ops.py (jit'd public wrapper) and ref.py (pure-jnp oracle);
 all are validated in interpret mode on CPU against their oracles
 (tests/test_kernels.py, tests/test_pam_matmul_engine.py,
-tests/test_pam_attention.py). Execution backend (compiled TPU vs CPU
-interpret) is resolved lazily per call by ``_backend.use_interpret()`` —
-never frozen at import time.
+tests/test_pam_attention.py) and compiled for TPU v5e without a chip
+(tests/test_tpu_compile.py). The platform alone picks compiled TPU or CPU
+interpret, per call, in ``_backend.use_interpret()`` — never frozen at
+import time.
 """

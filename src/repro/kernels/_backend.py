@@ -2,25 +2,17 @@
 
 Kernel wrappers must not freeze ``jax.default_backend()`` at import time:
 the platform can change after import (tests spawning CPU subprocesses with
-``XLA_FLAGS``, a host process that initialises TPU late, interpret-mode
-forcing in tooling). ``use_interpret()`` is therefore evaluated at *call*
-time; the result feeds the ``interpret=`` flag of ``pl.pallas_call`` and is
-a static jit argument, so each backend gets its own compiled executable.
+``XLA_FLAGS``, a host process that initialises TPU late). ``use_interpret()``
+is therefore evaluated at *call* time; the result feeds the ``interpret=``
+flag of ``pl.pallas_call`` and is a static jit argument, so each backend
+gets its own compiled executable. The platform alone decides: compiled
+Mosaic kernels on TPU, the Pallas interpreter everywhere else.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 
 
 def use_interpret() -> bool:
-    """True when Pallas kernels must run in interpret mode (no TPU present).
-
-    Override with ``REPRO_PALLAS_INTERPRET=0/1`` for debugging (e.g. forcing
-    interpret mode on a TPU host to bisect a lowering issue).
-    """
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None and env != "":
-        return env not in ("0", "false", "False")
+    """True when Pallas kernels must run in interpret mode (no TPU present)."""
     return jax.default_backend() != "tpu"

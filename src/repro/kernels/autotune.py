@@ -25,7 +25,10 @@ from __future__ import annotations
 # Defaults per (op, backend); per-shape entries in _TABLE override.
 _DEFAULTS = {
     ("pam_matmul", "interpret"): (256, 256, 256, 16),
-    ("pam_matmul", "tpu"): (128, 128, 512, 8),
+    # tpu tiles: untimed legal defaults. Blocks obey the (8, 128) rule;
+    # bk = 128 keeps the in-kernel contraction one 128-lane fori_loop and
+    # pads K = 576 to 640 rather than 1024.
+    ("pam_matmul", "tpu"): (128, 128, 128, 8),
     ("pa_softmax", "interpret"): (8,),
     ("pa_softmax", "tpu"): (8,),
     ("pam_attention", "interpret"): (256, 256, 16),

@@ -16,7 +16,9 @@ output block — gradients come back at true Hkv width with no ``jnp.repeat``
 materialisation anywhere (DESIGN.md §4.4).
 
 Masking is positional via explicit per-token position arrays (``q_pos``,
-``k_pos``) streamed alongside the operands: ``k_pos < 0`` marks
+``k_pos``) streamed alongside the operands — query positions as a column,
+key positions as a row, so the (bq, bk) mask is a plain broadcast compare
+on the TPU vector unit: ``k_pos < 0`` marks
 padded/empty KV slots (rejected in EVERY mode), causal compares
 ``k_pos <= q_pos`` and a static ``window`` bounds ``q_pos - k_pos`` — the
 same scheme the float flash kernel uses, generalised to arbitrary position
@@ -33,9 +35,11 @@ dK/dV. Each sweep recomputes its ``e``/``dP`` tiles exactly once. Grads
 match the unfused `_sdpa` composition within the streaming-rescale
 tolerance (DESIGN.md §4.2).
 
-Validated in interpret mode on CPU (the repo's reference backend); the
-grids and block specs follow the same batched-grid conventions as
-``pam_matmul`` for TPU compilation.
+Block shapes follow the TPU rule that a block's last two dims are
+multiples of (8, 128) or span the array: the per-row stats travel as
+(B*H, S, 1) columns and the key positions as a (1, T) row. Validated in
+interpret mode on CPU against the jnp engine, and compiled for TPU v5e in
+the test tier (tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
@@ -67,7 +71,7 @@ def _masked_scores(q, k, qp, kp, *, g, scale, causal, window,
                    fmt_name: str = "f32"):
     """PAM score tile with positional masking.
 
-    q: (bq, dh), k: (bk, dh), qp: (bq,) int32, kp: (bk,) int32. Masked
+    q: (bq, dh), k: (bk, dh), qp: (bq, 1) int32, kp: (1, bk) int32. Masked
     entries become exactly -1e30 — the same value the unfused path's
     ``where`` select uses, so paexp2 flushes them to an exact 0 (the
     bf16 rounding of -1e30 flushes identically).
@@ -77,11 +81,11 @@ def _masked_scores(q, k, qp, kp, *, g, scale, causal, window,
     s = pp.pam_dot(q, k.T, g).astype(dt)           # (bq, bk)
     if scale is not None:
         s = pp.pam(s, jnp.asarray(np.float32(scale), dt))
-    valid = (kp >= 0)[None, :]
+    valid = kp >= 0
     if causal:
-        valid &= kp[None, :] <= qp[:, None]
+        valid &= kp <= qp
     if window is not None:
-        valid &= (qp[:, None] - kp[None, :]) < window
+        valid &= (qp - kp) < window
     return jnp.where(valid, s, jnp.asarray(_NEG, dt))
 
 
@@ -96,6 +100,16 @@ def _delta_dsig(do, o, l, fmt_name: str = "f32"):
     pp = get_prims(fmt_name)
     prod = pp.pam(do, o).astype(jnp.float32)
     return -_padiv(jnp.sum(prod, axis=-1, keepdims=True), l)
+
+
+def _positions(q_pos, k_pos, sp, tp):
+    """Padded position operands: queries as an (sp, 1) column, keys as a
+    (1, tp) row; padding carries -1 (an empty slot, masked in every mode)."""
+    qpos = jnp.pad(q_pos.astype(jnp.int32), (0, sp - q_pos.shape[0]),
+                   constant_values=-1)[:, None]
+    kpos = jnp.pad(k_pos.astype(jnp.int32), (0, tp - k_pos.shape[0]),
+                   constant_values=-1)[None]
+    return qpos, kpos
 
 
 # ---------------------------------------------------------------------------
@@ -120,7 +134,7 @@ def _fwd_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref,
     q = q_ref[0]                                   # (bq, dh)
     k = k_ref[0]                                   # (bk, dh)
     v = v_ref[0]                                   # (bk, dh)
-    s = _masked_scores(q, k, qp_ref[0], kp_ref[0], g=g, scale=scale,
+    s = _masked_scores(q, k, qp_ref[...], kp_ref[...], g=g, scale=scale,
                        causal=causal, window=window, fmt_name=fmt_name)
 
     m_prev = m_ref[...]                            # (bq, 1) f32
@@ -143,8 +157,8 @@ def _fwd_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref, m_out_ref,
     @pl.when(kv == nk - 1)
     def _out():
         o_ref[0] = _padiv(acc_ref[...], l_ref[...]).astype(o_ref.dtype)
-        m_out_ref[0] = m_ref[...][:, 0]
-        l_out_ref[0] = l_ref[...][:, 0]
+        m_out_ref[0] = m_ref[...]
+        l_out_ref[0] = l_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",
@@ -172,10 +186,7 @@ def pam_flash_attention_fwd_bh(q, k, v, q_pos, k_pos, *, causal: bool,
     qp = jnp.pad(q.astype(dt), ((0, 0), (0, sp - s_len), (0, 0)))
     kp = jnp.pad(k.astype(dt), ((0, 0), (0, tp - t), (0, 0)))
     vp = jnp.pad(v.astype(dt), ((0, 0), (0, tp - t), (0, 0)))
-    qpos = jnp.pad(q_pos.astype(jnp.int32), (0, sp - s_len),
-                   constant_values=-1)[None]
-    kpos = jnp.pad(k_pos.astype(jnp.int32), (0, tp - t),
-                   constant_values=-1)[None]
+    qpos, kpos = _positions(q_pos, k_pos, sp, tp)
     nk = tp // bk_
 
     o, m, l = pl.pallas_call(
@@ -183,7 +194,7 @@ def pam_flash_attention_fwd_bh(q, k, v, q_pos, k_pos, *, causal: bool,
                           window=window, scale=scale, fmt_name=fmt_name),
         grid=(bh, sp // bq_, nk),
         in_specs=[
-            pl.BlockSpec((1, bq_), lambda b, i, j: (0, i)),
+            pl.BlockSpec((bq_, 1), lambda b, i, j: (i, 0)),
             pl.BlockSpec((1, bk_), lambda b, i, j: (0, j)),
             pl.BlockSpec((1, bq_, dh), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk_, dh), lambda b, i, j: (b // rep, j, 0)),
@@ -191,13 +202,13 @@ def pam_flash_attention_fwd_bh(q, k, v, q_pos, k_pos, *, causal: bool,
         ],
         out_specs=[
             pl.BlockSpec((1, bq_, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq_), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, bq_), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, bq_, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq_, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sp, dh), dt),
-            jax.ShapeDtypeStruct((bh, sp), jnp.float32),
-            jax.ShapeDtypeStruct((bh, sp), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sp, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq_, dh), jnp.float32),
@@ -205,8 +216,9 @@ def pam_flash_attention_fwd_bh(q, k, v, q_pos, k_pos, *, causal: bool,
             pltpu.VMEM((bq_, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="pam_attention_fwd",
     )(qpos, kpos, qp, kp, vp)
-    return o[:, :s_len], m[:, :s_len], l[:, :s_len]
+    return o[:, :s_len], m[:, :s_len, 0], l[:, :s_len, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -242,14 +254,13 @@ def _dq_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref, do_ref, m_ref,
     @pl.when(kv == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        dsig_acc[...] = _delta_dsig(do_ref[0], o_ref[0],
-                                    l_ref[0][:, None], fmt_name)
+        dsig_acc[...] = _delta_dsig(do_ref[0], o_ref[0], l_ref[0], fmt_name)
 
-    s = _masked_scores(q_ref[0], k_ref[0], qp_ref[0], kp_ref[0], g=g,
+    s = _masked_scores(q_ref[0], k_ref[0], qp_ref[...], kp_ref[...], g=g,
                        scale=scale, causal=causal, window=window,
                        fmt_name=fmt_name)
-    m = m_ref[0][:, None]
-    l = l_ref[0][:, None]
+    m = m_ref[0]
+    l = l_ref[0]
     e = pp.paexp2(pp.pam(s - m.astype(dt), l2e))   # masked entries: exact 0
     dp = pp.pam_dot(do_ref[0], v_ref[0].T, g)      # (bq, bk) f32
     ds = _ds_tile(e, dp, l, dsig_acc[...], scale=scale, fmt_name=fmt_name)
@@ -258,7 +269,7 @@ def _dq_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref, do_ref, m_ref,
     @pl.when(kv == nk - 1)
     def _out():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
-        dsig_ref[0] = dsig_acc[...][:, 0]
+        dsig_ref[0] = dsig_acc[...]
 
 
 # ---------------------------------------------------------------------------
@@ -285,11 +296,12 @@ def _dkv_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, do_ref, m_ref, l_ref,
 
     q = q_ref[0]
     do = do_ref[0]
-    s = _masked_scores(q, k_ref[0], qp_ref[0], kp_ref[0], g=g, scale=scale,
-                       causal=causal, window=window, fmt_name=fmt_name)
-    m = m_ref[0][:, None]
-    l = l_ref[0][:, None]
-    dsig = dsig_ref[0][:, None]
+    s = _masked_scores(q, k_ref[0], qp_ref[...], kp_ref[...], g=g,
+                       scale=scale, causal=causal, window=window,
+                       fmt_name=fmt_name)
+    m = m_ref[0]
+    l = l_ref[0]
+    dsig = dsig_ref[0]
     e = pp.paexp2(pp.pam(s - m.astype(dt), l2e))
     # p = e / l in f32 (l is an f32 stat), rounded once to the carrier for
     # the Pᵀ·dO tile product; masked rows stay an exact 0.
@@ -328,19 +340,16 @@ def pam_flash_attention_bwd_bh(q, k, v, q_pos, k_pos, o, m, l, do, *,
     vp = jnp.pad(v.astype(dt), ((0, 0), (0, tp - t), (0, 0)))
     op = jnp.pad(o.astype(dt), ((0, 0), (0, sp - s_len), (0, 0)))
     dop = jnp.pad(do.astype(dt), ((0, 0), (0, sp - s_len), (0, 0)))
-    mp = jnp.pad(m, ((0, 0), (0, sp - s_len)), constant_values=_NEG)
-    lp = jnp.pad(l, ((0, 0), (0, sp - s_len)), constant_values=1.0)
-    qpos = jnp.pad(q_pos.astype(jnp.int32), (0, sp - s_len),
-                   constant_values=-1)[None]
-    kpos = jnp.pad(k_pos.astype(jnp.int32), (0, tp - t),
-                   constant_values=-1)[None]
+    mp = jnp.pad(m, ((0, 0), (0, sp - s_len)), constant_values=_NEG)[..., None]
+    lp = jnp.pad(l, ((0, 0), (0, sp - s_len)), constant_values=1.0)[..., None]
+    qpos, kpos = _positions(q_pos, k_pos, sp, tp)
     nk, nq = tp // bk_, sp // bq_
 
-    pos_q_spec = pl.BlockSpec((1, bq_), lambda b, i, j: (0, i))
+    pos_q_spec = pl.BlockSpec((bq_, 1), lambda b, i, j: (i, 0))
     pos_k_spec = pl.BlockSpec((1, bk_), lambda b, i, j: (0, j))
     q_spec = pl.BlockSpec((1, bq_, dh), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, bk_, dh), lambda b, i, j: (b // rep, j, 0))
-    row_spec = pl.BlockSpec((1, bq_), lambda b, i, j: (b, i))
+    row_spec = pl.BlockSpec((1, bq_, 1), lambda b, i, j: (b, i, 0))
 
     dq, dsig = pl.pallas_call(
         functools.partial(_dq_kernel, g=g, nk=nk, causal=causal,
@@ -351,13 +360,14 @@ def pam_flash_attention_bwd_bh(q, k, v, q_pos, k_pos, o, m, l, do, *,
         out_specs=[q_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sp, dh), dt),
-            jax.ShapeDtypeStruct((bh, sp), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq_, dh), jnp.float32),
             pltpu.VMEM((bq_, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="pam_attention_dq",
     )(qpos, kpos, qp, kp, vp, op, dop, mp, lp)
 
     # KV-outer grid for dK/dV: KV tiles are indexed by program_id(1), the
@@ -367,15 +377,15 @@ def pam_flash_attention_bwd_bh(q, k, v, q_pos, k_pos, o, m, l, do, *,
                           window=window, scale=scale, fmt_name=fmt_name),
         grid=(bkv, nk, rep, nq),
         in_specs=[
-            pl.BlockSpec((1, bq_), lambda b, j, r, i: (0, i)),
+            pl.BlockSpec((bq_, 1), lambda b, j, r, i: (i, 0)),
             pl.BlockSpec((1, bk_), lambda b, j, r, i: (0, j)),
             pl.BlockSpec((1, bq_, dh), lambda b, j, r, i: (b * rep + r, i, 0)),
             pl.BlockSpec((1, bk_, dh), lambda b, j, r, i: (b, j, 0)),
             pl.BlockSpec((1, bk_, dh), lambda b, j, r, i: (b, j, 0)),
             pl.BlockSpec((1, bq_, dh), lambda b, j, r, i: (b * rep + r, i, 0)),
-            pl.BlockSpec((1, bq_), lambda b, j, r, i: (b * rep + r, i)),
-            pl.BlockSpec((1, bq_), lambda b, j, r, i: (b * rep + r, i)),
-            pl.BlockSpec((1, bq_), lambda b, j, r, i: (b * rep + r, i)),
+            pl.BlockSpec((1, bq_, 1), lambda b, j, r, i: (b * rep + r, i, 0)),
+            pl.BlockSpec((1, bq_, 1), lambda b, j, r, i: (b * rep + r, i, 0)),
+            pl.BlockSpec((1, bq_, 1), lambda b, j, r, i: (b * rep + r, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk_, dh), lambda b, j, r, i: (b, j, 0)),
@@ -390,6 +400,7 @@ def pam_flash_attention_bwd_bh(q, k, v, q_pos, k_pos, o, m, l, do, *,
             pltpu.VMEM((bk_, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="pam_attention_dkv",
     )(qpos, kpos, qp, kp, vp, dop, mp, lp, dsig)
 
     return dq[:, :s_len], dk[:, :t], dv[:, :t]

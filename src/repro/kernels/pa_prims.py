@@ -4,7 +4,7 @@ This is the single kernel-side home of the float32 bit constants and the
 piecewise-affine scalar helpers (``_pam`` / ``_padiv`` / ``_paexp2`` /
 ``_palog2``) that were previously duplicated across ``pa_softmax``,
 ``pam_eltwise`` and ``pam_matmul``; it also hosts the grouped PAM *tile*
-product (``_prep_tiles`` + ``_grouped_pam_sum``, DESIGN.md §2.1) that both
+product (``_pam_dot`` on the shared ``_contract`` loop, DESIGN.md §2.1) that
 the matmul kernels and the fused PAM flash-attention kernel compose.
 
 The constants are spelled as literal numpy int32 scalars — not imports from
@@ -25,6 +25,8 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import floatbits as _fb
 
@@ -92,7 +94,9 @@ LMUL_REL_PLUS = 1.0 / 16.0                   # +2^-l at fa = fb = 0
 # ---------------------------------------------------------------------------
 
 def _pam(a, b):
-    """Elementwise PAM a ·̂ b for finite/zero float32 (kernel contract)."""
+    """Elementwise PAM a ·̂ b for finite/zero float32 (kernel contract).
+    Operands broadcast first: a TPU kernel bitcasts vectors, never scalars."""
+    a, b = jnp.broadcast_arrays(a, b)
     ai = jax.lax.bitcast_convert_type(a, jnp.int32)
     bi = jax.lax.bitcast_convert_type(b, jnp.int32)
     sign = (ai ^ bi) & _SIGN
@@ -106,6 +110,7 @@ def _pam(a, b):
 
 def _padiv(a, b):
     """Elementwise PA division a ÷̂ b for finite/zero a, finite nonzero b."""
+    a, b = jnp.broadcast_arrays(a, b)
     ai = jax.lax.bitcast_convert_type(a, jnp.int32)
     bi = jax.lax.bitcast_convert_type(b, jnp.int32)
     sign = (ai ^ bi) & _SIGN
@@ -138,90 +143,152 @@ def _palog2(a):
 # ---------------------------------------------------------------------------
 # Grouped PAM tile product (DESIGN.md §2.1) — shared by the pam_matmul
 # kernels and the fused PAM flash-attention kernel.
+#
+# A contraction is a sum of rank-1 "outer" steps: step c combines column c
+# of the A-side arrays (M, C) with row c of the B-side arrays (C, N). On the
+# TPU vector unit a column is a lane slice broadcast across lanes and a row
+# is a sublane slice broadcast across sublanes; no gather is ever formed.
+# Two-level reduction: ``g`` steps accumulate into a group partial, and the
+# group partials add into the running sum in contraction order.
+#
+# Mosaic layout rules decide the loop form. A contraction whose length is
+# a multiple of 128 lanes runs as a ``fori_loop`` over groups: the A side is
+# rotated (``pltpu.roll``) so the group's first column sits in lane 0, and
+# the B side is stashed in scoped VMEM so each group loads its ``g`` rows
+# with one aligned dynamic sublane slice. Shorter or unaligned contractions
+# (the attention head dim, decode-sized tails, small test tiles) unroll
+# statically. Both forms add in the same order, so a tile product's bits do
+# not depend on which one ran.
 # ---------------------------------------------------------------------------
 
-def _prep_tiles(a, b):
-    """Bitcast both tiles once. Returns (saT, amT, sb, bmg, bz):
-    A side k-major with the zero SENTINEL applied to its magnitudes,
-    B side with the PAM re-bias folded in (one add saved per inner element)
-    plus an explicit zero MASK — the sentinel trick only flushes against a
-    bias-folded partner (floatbits.PAM_ZERO_SENTINEL has the derivation).
+def _contract(a_side, b_side, product, g):
+    """Sum over the contraction axis of ``product(cols, rows)``.
+
+    a_side: tuple of (M, C) carrier arrays; b_side: tuple of (C, N) carrier
+    arrays; ``product`` maps the step's (M, 1) columns and (1, N) rows to
+    an (M, N) f32 partial. ``g`` must divide C.
     """
-    ai = jax.lax.bitcast_convert_type(a, jnp.int32)
-    bi = jax.lax.bitcast_convert_type(b, jnp.int32)
-    # Zero tests are FLOAT compares: under flush-to-zero arithmetic (CPU
-    # and TPU) denormal inputs equal 0.0, matching pam_value's semantics.
-    # The B mask is an int AND-mask (0 where b==0, else ~0) — one vpand per
-    # inner element instead of a bool select.
-    amT = jnp.where(a == 0.0, _ZSENT, ai & _MAG).T
-    bzM = jnp.where(b == 0.0, 0, -1).astype(jnp.int32)
-    return (ai & _SIGN).T, amT, bi & _SIGN, (bi & _MAG) - _BIAS, bzM
+    c_len = a_side[0].shape[1]
+    ng = c_len // g
+
+    def group(cols, rows, base):
+        part = None
+        for j in range(base, base + g):
+            p = product(tuple(x[:, j:j + 1] for x in cols),
+                        tuple(y[j:j + 1, :] for y in rows))
+            part = p if part is None else part + p
+        return part
+
+    if c_len % 128 or ng < 2:
+        acc = None
+        for q in range(ng):
+            part = group(a_side, b_side, q * g)
+            acc = part if acc is None else acc + part
+        return acc
+
+    def run(*scratch):
+        for ref, y in zip(scratch, b_side):
+            ref[...] = y
+
+        def body(q, acc):
+            off = pl.multiple_of(q * g, g)
+            cols = tuple(pltpu.roll(x, c_len - off, 1) for x in a_side)
+            rows = tuple(ref[pl.ds(off, g), :] for ref in scratch)
+            return acc + group(cols, rows, 0)
+
+        return jax.lax.fori_loop(1, ng, body, group(a_side, b_side, 0))
+
+    return pl.run_scoped(run, *[pltpu.VMEM(y.shape, y.dtype)
+                                for y in b_side])
 
 
-def _grouped_pam_sum(saT, amT, sb, bmg, bzM, g):
-    """Sum of PAM products over K for int-prepped tiles.
-
-    saT/amT: (bk, bm) sign bits / magnitude (A side, zero-sentineled),
-    sb/bmg:  (bk, bn) sign bits / magnitude-minus-bias (B side),
-    bzM:     (bk, bn) int32 AND-mask, 0 where B is ±0.0 else ~0.
-    Returns the (bm, bn) f32 partial result. The K axis is processed as
-    bk//g groups of g slices; each group's g products accumulate in
-    registers before one (bk//g, bm, bn) vector reduction.
-
-    NOTE: keep this in sync with core/matmul.py::_grouped_pam_sum (same
-    algorithm on the jnp engine's batched layout).
-    """
-    bk, bm = amT.shape
-    bn = bmg.shape[1]
-    amT = amT.reshape(bk // g, g, bm)
-    saT = saT.reshape(bk // g, g, bm)
-    bmg = bmg.reshape(bk // g, g, bn)
-    sb = sb.reshape(bk // g, g, bn)
-    bzM = bzM.reshape(bk // g, g, bn)
-    part = None
-    for j in range(g):
-        mag = amT[:, j, :, None] + bmg[:, j, None, :]
-        mag = jnp.where(mag < _MIN_NORM, 0, jnp.minimum(mag, _MAX_FINITE))
-        mag = mag & bzM[:, j, None, :]                 # PAM(a, ±0) = ±0
-        bits = (saT[:, j, :, None] ^ sb[:, j, None, :]) | mag
-        p = jax.lax.bitcast_convert_type(bits, jnp.float32)
-        part = p if part is None else part + p
-    return jnp.sum(part, axis=0)
-
-
-def _pam_dot(a, b, g):
-    """(bm, bk) ·̂ (bk, bn) PAM tile product: prep + grouped sum, with ``g``
-    lowered to the largest divisor of the contraction axis."""
-    bk = a.shape[-1]
-    g_ = max(1, min(g, bk))
-    while bk % g_:
+def _largest_divisor(n, g):
+    g_ = max(1, min(g, n))
+    while n % g_:
         g_ -= 1
-    return _grouped_pam_sum(*_prep_tiles(a, b), g_)
+    return g_
+
+
+def _make_pam_dot(fmt, fold):
+    """(bm, bk) ·̂ (bk, bn) PAM tile product for one carrier format.
+
+    Prep bitcasts each tile once: the A side keeps sign bits and
+    zero-SENTINELED magnitudes, the B side sign bits, magnitudes with the
+    re-bias ``fold`` subtracted and an explicit zero AND-mask (the sentinel
+    only flushes against a bias-folded partner — floatbits.PAM_ZERO_SENTINEL
+    has the derivation). Each product is one carrier add, the flush/clamp
+    select, the mask, the sign xor/or — then its exact f32 embedding (a
+    16-bit pattern shifted into the high half) joins the f32 sum.
+    """
+    SIGN, MAG = fmt.SIGN_MASK, fmt.MAG_MASK
+    MINN, MAXF, ZSENT = fmt.MIN_NORM, fmt.MAX_FINITE, fmt.ZERO_SENTINEL
+    to_f32 = np.int32(32 - fmt.width)
+    ZERO, NEG1 = fmt.np_carrier(0), fmt.np_carrier(-1)
+
+    def is_zero(x, xi):
+        if fmt.width == 32:
+            # Float compare: flush-to-zero backends make denormals == 0.0.
+            return x == 0.0
+        # Exponent-field test: explicit denormal flush in the carrier.
+        return (xi & fmt.EXP_MASK) == ZERO
+
+    def product(cols, rows):
+        sa, am = cols
+        sb, bmg, bzm = rows
+        mag = am + bmg
+        mag = jnp.where(mag < MINN, ZERO, jnp.minimum(mag, MAXF)) & bzm
+        bits = ((sa ^ sb) | mag).astype(jnp.int32)
+        if to_f32:
+            bits = bits << to_f32
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+    def pam_dot(a, b, g):
+        ai = _fb.bits(a, fmt)
+        bi = _fb.bits(b, fmt)
+        a_side = (ai & SIGN, jnp.where(is_zero(a, ai), ZSENT, ai & MAG))
+        b_side = (bi & SIGN, (bi & MAG) - fold,
+                  jnp.where(is_zero(b, bi), ZERO, NEG1))
+        return _contract(a_side, b_side, product,
+                         _largest_divisor(a.shape[-1], g))
+
+    return pam_dot
+
+
+# (bm, bk) ·̂ (bk, bn) f32 PAM tile product, ``g`` lowered to the largest
+# divisor of the contraction axis.
+_pam_dot = _make_pam_dot(_fb.FLOAT32, _BIAS)
 
 
 # ---------------------------------------------------------------------------
 # Per-format prims (FloatFormat engine family, DESIGN.md §11).
 #
-# ``get_prims(fmt_name, lmul)`` returns a namespace with the same seven
-# helpers as the module level, specialised to one FloatFormat: constants in
-# the format's carrier dtype (int16 for bf16/f16 — native lane width, no f32
-# round-trip) and, when ``lmul`` is set, the L-Mul mantissa offset folded
-# into the re-bias (one fused constant, zero extra adds per product).
+# ``get_prims(fmt_name, lmul)`` returns a namespace with the same five
+# helpers as the module level, specialised to one FloatFormat and, when
+# ``lmul`` is set, the L-Mul mantissa offset folded into the re-bias (one
+# fused constant, zero extra adds per product).
 #
 # The ("f32", lmul=False) instance binds the module-level functions verbatim,
 # so the historical f32 path is bit-identical by construction, not by test.
 #
-# Narrow-format semantics (the deltas vs the f32 kernel contract):
-#   * zero test is the EXPONENT FIELD, not a float compare — int16 carriers
-#     see bf16 denormals explicitly, so the flush documented by the absint
+# Kernel carriers are int32 for every format: narrow formats run on their
+# widened FloatFormat (16-bit patterns sign-extended into int32), because
+# the TPU v5e vector unit has no 16-bit integer compares or shifts and no
+# bf16 compares, floor or round. Float compares, floor and round of narrow
+# values therefore run on their exact f32 embedding; float adds and the
+# operands/results stay in the format's dtype. Against the int16-carrier
+# jnp engine (core/pam.py, core/matmul.py) the narrow-format deltas are:
+#   * zero test is the EXPONENT FIELD, not a float compare — the carrier
+#     sees bf16 denormals explicitly, so the flush documented by the absint
 #     domain (quantize-then-flush below 2^-126) is spelled out in bits;
 #   * products below MIN_NORM flush to +0, magnitude sums saturate at
-#     MAX_FINITE; the disjoint-ranges overflow test ``mag < -BIAS`` holds in
-#     int16 exactly as in int32 (wrapped overflow lands in
-#     [-32768, -16514], genuine underflow in (-16256, 0));
-#   * grouped tile products keep each PAM product in the carrier but
-#     ACCUMULATE IN F32 (exact bf16->f32 embedding), matching the kernels'
-#     f32 VMEM scratch posture.
+#     MAX_FINITE; a sum that wraps the int16 carrier lands on the same clamp
+#     in int32 (the disjoint-ranges overflow test ``mag < -BIAS`` never
+#     fires there, the ``minimum`` does) — bit-identical results;
+#   * grouped tile products keep each PAM product's bits and ACCUMULATE IN
+#     F32 (exact bf16->f32 embedding), matching the kernels' f32 VMEM
+#     scratch posture. A tile product whose per-product magnitude reaches
+#     2^128 is outside the contract: the int32 sum saturates where the int16
+#     engine's wraps and flushes.
 # ---------------------------------------------------------------------------
 
 
@@ -229,7 +296,7 @@ class Prims:
     """Bound PA primitives for one (FloatFormat, lmul) pair."""
 
     __slots__ = ("fmt", "lmul", "pam", "padiv", "paexp2", "palog2",
-                 "prep_tiles", "grouped_pam_sum", "pam_dot")
+                 "pam_dot")
 
     def __init__(self, fmt, lmul, **fns):
         self.fmt = fmt
@@ -238,20 +305,23 @@ class Prims:
             setattr(self, k, v)
 
 
-def _build_prims(fmt, lmul):
-    nc = fmt.np_carrier
-    C = fmt.carrier
+def _build_prims(wf, lmul):
+    """Prims on carrier format ``wf``: ``get_prims`` passes the widened
+    (int32) format; the int16 instance exists only to pin the two equal."""
+    fmt = _fb.FORMATS[wf.name]
+    nc = wf.np_carrier
+    C = wf.carrier
     dt = fmt.dtype
-    SIGN, MAG, EXP, MAN = fmt.SIGN_MASK, fmt.MAG_MASK, fmt.EXP_MASK, fmt.MAN_MASK
-    BIAS, MINN, MAXF = fmt.BIAS_SHIFTED, fmt.MIN_NORM, fmt.MAX_FINITE
-    ZSENT = fmt.ZERO_SENTINEL
+    SIGN, MAG, MAN = wf.SIGN_MASK, wf.MAG_MASK, wf.MAN_MASK
+    BIAS, MINN, MAXF = wf.BIAS_SHIFTED, wf.MIN_NORM, wf.MAX_FINITE
+    EXP = wf.EXP_MASK
     MB = fmt.man_bits
     # L-Mul folds its +2^-l mantissa offset into the re-bias constant. The
     # sentinel/overflow band proofs absorb the shift: it is <= 2^(MB-3),
     # tiny against the 2^MB-wide guard bands (checked for both carriers in
     # tests/test_format_dispatch.py).
-    FOLD = nc(int(BIAS) - (int(fmt.LMUL_OFFSET) if lmul else 0))
-    ZERO, NEG1 = nc(0), nc(-1)
+    FOLD = nc(int(BIAS) - (int(wf.LMUL_OFFSET) if lmul else 0))
+    ZERO = nc(0)
     shMB = nc(MB)
 
     if fmt.width == 32:
@@ -264,27 +334,29 @@ def _build_prims(fmt, lmul):
             return (xi & EXP) == ZERO
 
     def pam(a, b):
-        ai = jax.lax.bitcast_convert_type(a, C)
-        bi = jax.lax.bitcast_convert_type(b, C)
+        a, b = jnp.broadcast_arrays(a, b)
+        ai = _fb.bits(a, wf)
+        bi = _fb.bits(b, wf)
         sign = (ai ^ bi) & SIGN
         mag = (ai & MAG) + (bi & MAG) - FOLD
         ovf = mag < -BIAS       # disjoint-ranges carrier overflow test
         mag = jnp.where(mag < MINN, ZERO, jnp.minimum(mag, MAXF))
         mag = jnp.where(ovf, MAXF, mag)
-        out = jax.lax.bitcast_convert_type(sign | mag, dt)
+        out = _fb.floats(sign | mag, wf)
         zero = _is_zero(a, ai) | _is_zero(b, bi)
         return jnp.where(zero, jnp.zeros((), dt), out)
 
     def padiv(a, b):
         # L-Mul is a product approximation only; division keeps plain PA.
-        ai = jax.lax.bitcast_convert_type(a, C)
-        bi = jax.lax.bitcast_convert_type(b, C)
+        a, b = jnp.broadcast_arrays(a, b)
+        ai = _fb.bits(a, wf)
+        bi = _fb.bits(b, wf)
         sign = (ai ^ bi) & SIGN
         mag = (ai & MAG) - (bi & MAG) + BIAS
         ovf = mag < -BIAS
         mag = jnp.where(mag < MINN, ZERO, jnp.minimum(mag, MAXF))
         mag = jnp.where(ovf, MAXF, mag)
-        out = jax.lax.bitcast_convert_type(sign | mag, dt)
+        out = _fb.floats(sign | mag, wf)
         return jnp.where(_is_zero(a, ai), jnp.zeros((), dt), out)
 
     def paexp2(a):
@@ -292,58 +364,22 @@ def _build_prims(fmt, lmul):
         # (powers of two); for a < 128 the biased exponent fits the carrier
         # un-wrapped, and a >= 128 is overridden to +inf below.
         ac = jnp.clip(a, -16384.0, 16384.0)
-        n = jnp.floor(ac)
-        man = jnp.round((ac - n) * jnp.asarray(2.0**MB, dt)).astype(C)
+        n = jnp.floor(_fb.cmp_view(ac, wf)).astype(dt)
+        man = jnp.round(_fb.cmp_view((ac - n) * jnp.asarray(2.0**MB, dt),
+                                     wf)).astype(C)
         e = n.astype(C) + (man >> shMB) + nc(fmt.exp_bias)
         mag = (e << shMB) | (man & MAN)
         mag = jnp.where(e <= ZERO, ZERO, jnp.minimum(mag, MAXF))
-        out = jax.lax.bitcast_convert_type(mag, dt)
-        return jnp.where(a >= 128.0, jnp.asarray(jnp.inf, dt), out)
+        out = _fb.floats(mag, wf)
+        return jnp.where(_fb.cmp_view(a, wf) >= 128.0,
+                         jnp.asarray(jnp.inf, dt), out)
 
     def palog2(a):
-        i = jax.lax.bitcast_convert_type(a, C)
+        i = _fb.bits(a, wf)
         return (i - BIAS).astype(dt) * jnp.asarray(2.0**-MB, dt)
 
-    def prep_tiles(a, b):
-        ai = jax.lax.bitcast_convert_type(a, C)
-        bi = jax.lax.bitcast_convert_type(b, C)
-        az = _is_zero(a, ai)
-        bz = _is_zero(b, bi)
-        amT = jnp.where(az, ZSENT, ai & MAG).T
-        bzM = jnp.where(bz, ZERO, NEG1)
-        return (ai & SIGN).T, amT, bi & SIGN, (bi & MAG) - FOLD, bzM
-
-    def grouped_pam_sum(saT, amT, sb, bmg, bzM, g):
-        bk, bm = amT.shape
-        bn = bmg.shape[1]
-        amT = amT.reshape(bk // g, g, bm)
-        saT = saT.reshape(bk // g, g, bm)
-        bmg = bmg.reshape(bk // g, g, bn)
-        sb = sb.reshape(bk // g, g, bn)
-        bzM = bzM.reshape(bk // g, g, bn)
-        part = None
-        for j in range(g):
-            mag = amT[:, j, :, None] + bmg[:, j, None, :]
-            mag = jnp.where(mag < MINN, ZERO, jnp.minimum(mag, MAXF))
-            mag = mag & bzM[:, j, None, :]
-            bits = (saT[:, j, :, None] ^ sb[:, j, None, :]) | mag
-            p = jax.lax.bitcast_convert_type(bits, dt)
-            # Accumulate partials in f32 (exact embedding for bf16/f16;
-            # a no-op cast on the f32 path).
-            p = p.astype(jnp.float32)
-            part = p if part is None else part + p
-        return jnp.sum(part, axis=0)
-
-    def pam_dot(a, b, g):
-        bk = a.shape[-1]
-        g_ = max(1, min(g, bk))
-        while bk % g_:
-            g_ -= 1
-        return grouped_pam_sum(*prep_tiles(a, b), g_)
-
-    return Prims(fmt, lmul, pam=pam, padiv=padiv, paexp2=paexp2,
-                 palog2=palog2, prep_tiles=prep_tiles,
-                 grouped_pam_sum=grouped_pam_sum, pam_dot=pam_dot)
+    return Prims(wf, lmul, pam=pam, padiv=padiv, paexp2=paexp2,
+                 palog2=palog2, pam_dot=_make_pam_dot(wf, FOLD))
 
 
 @functools.lru_cache(maxsize=None)
@@ -356,6 +392,5 @@ def get_prims(fmt_name: str = "f32", lmul: bool = False) -> Prims:
     fmt = _fb.FORMATS[fmt_name]
     if fmt_name == "f32" and not lmul:
         return Prims(fmt, False, pam=_pam, padiv=_padiv, paexp2=_paexp2,
-                     palog2=_palog2, prep_tiles=_prep_tiles,
-                     grouped_pam_sum=_grouped_pam_sum, pam_dot=_pam_dot)
-    return _build_prims(fmt, lmul)
+                     palog2=_palog2, pam_dot=_pam_dot)
+    return _build_prims(fmt.widened, lmul)

@@ -52,5 +52,6 @@ def pa_softmax_rows(x, *, rows: int = 8, interpret: bool = True,
         out_specs=pl.BlockSpec((rows, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, c), fmt.dtype),
         interpret=interpret,
+        name="pa_softmax",
     )(xp)
     return out[:r]

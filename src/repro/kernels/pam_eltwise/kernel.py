@@ -59,6 +59,7 @@ def eltwise_binary(a, b, *, op: str = "pam", interpret: bool = True,
         out_specs=pl.BlockSpec((_ROWS, _COLS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(av.shape, dt),
         interpret=interpret,
+        name=f"pam_eltwise_{op}",
     )(av, bv)
     return out.reshape(-1)[:n].reshape(shape)
 
@@ -79,5 +80,6 @@ def eltwise_unary(a, *, op: str = "paexp2", interpret: bool = True,
         out_specs=pl.BlockSpec((_ROWS, _COLS), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(av.shape, dt),
         interpret=interpret,
+        name=f"pam_eltwise_{op}",
     )(av)
     return out.reshape(-1)[:n].reshape(shape)

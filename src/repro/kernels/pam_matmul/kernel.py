@@ -2,16 +2,14 @@
 adapted from CUDA to the TPU memory hierarchy — DESIGN.md §2).
 
 The MXU multiplies natively and cannot execute the bit-level PAM algorithm,
-so the kernels run on the **VPU** (8x128 int lanes). The scalar-k loop of the
-first kernel generation (one rank-1 outer product per K element) is replaced
-by *grouped k-blocks*: the whole (bm, bk) / (bk, bn) tiles are bitcast to
-int32 once, split into ``bk // g`` groups of ``g`` k-slices, and each group
-accumulates its ``g`` PAM products elementwise into one (bk//g, bm, bn)
-partial-sums block that a single vector reduction collapses onto the VMEM
-accumulator. Two levels of reduction — in-register over the group, vector
-reduce over groups — keep every intermediate small enough to stay on-chip
-while giving the compiler long straight-line vector code instead of a
-512-iteration sequential loop.
+so the kernels run on the **VPU** (8x128 int lanes). Each grid step bitcasts
+its (bm, bk) / (bk, bn) tiles to int32 once and contracts them as a sum of
+rank-1 steps — an A column broadcast across lanes against a B row broadcast
+across sublanes — with ``g`` steps accumulating in registers before each
+group partial joins the sum (``pa_prims._contract``: a ``fori_loop`` over
+128-lane-aligned contractions, a static unroll for short ones). No
+intermediate is larger than one (bm, bn) tile, so every kernel stays well
+inside the scoped VMEM limit.
 
 Grid is (B, M/bm, N/bn, K/bk) with the K dimension innermost so each
 (b, i, j) output tile's accumulator lives in VMEM across all K steps
@@ -46,8 +44,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import floatbits as _fb
 from .. import autotune as _autotune
 from ..pa_prims import (_SIGN, _MAG, _EXP, _MAN, _BIAS, _MIN_NORM, _MAX_EXPF,
-                        _MAX_FINITE, _ZSENT, _prep_tiles, _grouped_pam_sum,
-                        get_prims)
+                        _MAX_FINITE, _contract, get_prims)
 
 
 # ---------------------------------------------------------------------------
@@ -87,19 +84,26 @@ def _fit(bm, bn, bk, g, m, n, k, *, group_dim: str = "k"):
 # Forward kernel: out[b] = A[b] ·̂ B[b]   (batched grid).
 # ---------------------------------------------------------------------------
 
+def _accumulate(acc_ref, part, step):
+    """acc = part on the first contraction step, acc += part after it (no
+    0.0 + x, so an all-zero contraction keeps its product's zero sign)."""
+    @pl.when(step == 0)
+    def _first():
+        acc_ref[...] = part
+
+    @pl.when(step > 0)
+    def _rest():
+        acc_ref[...] += part
+
+
 def _fwd_kernel(a_ref, b_ref, o_ref, acc_ref, *, g: int, nk: int,
                 fmt_name: str = "f32", lmul: bool = False):
     pp = get_prims(fmt_name, lmul)
+    kk = pl.program_id(3)
+    # (bm, bk) ·̂ (bk, bn) in the format's dtype, f32 partial
+    _accumulate(acc_ref, pp.pam_dot(a_ref[0], b_ref[0], g), kk)
 
-    @pl.when(pl.program_id(3) == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    a = a_ref[0]                                   # (bm, bk) fmt dtype, VMEM
-    b = b_ref[0]                                   # (bk, bn)
-    acc_ref[...] += pp.grouped_pam_sum(*pp.prep_tiles(a, b), g)
-
-    @pl.when(pl.program_id(3) == nk - 1)
+    @pl.when(kk == nk - 1)
     def _out():
         # Narrow formats round the f32 accumulator back to the operand
         # dtype on the single output store (a no-op cast on the f32 path).
@@ -152,6 +156,7 @@ def pam_matmul_batched(a, b, *, bm: int, bn: int, bk: int, g: int,
         out_shape=jax.ShapeDtypeStruct((B, mp, np_), fmt.dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
         interpret=interpret,
+        name="pam_matmul",
     )(a, b)
     return out[:, :m, :n]
 
@@ -176,54 +181,37 @@ def pam_matmul_2d(a, b, *, bm: int = 128, bn: int = 128, bk: int = 512,
 # ---------------------------------------------------------------------------
 
 def _exact_da_kernel(a_ref, b_ref, g_ref, o_ref, acc_ref, *, g: int, nn: int):
-    @pl.when(pl.program_id(3) == 0)
-    def _zero():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
     a = a_ref[0]                                   # (bm, bkk)
     b = b_ref[0]                                   # (bkk, bn)
     gr = g_ref[0]                                  # (bm, bn)
-    bm, bkk = a.shape
-    bn = b.shape[1]
-
     ai = jax.lax.bitcast_convert_type(a, jnp.int32)
     bi = jax.lax.bitcast_convert_type(b, jnp.int32)
     gi = jax.lax.bitcast_convert_type(gr, jnp.int32)
     maf_a = ai & _MAN                              # (bm, bkk) mantissa field
-    # B side, transposed to n-major: (bn, bkk)
-    ebT = (bi & _EXP).T                            # biased exponent field<<23
-    sbT = (bi & _SIGN).T
-    mbT = (bi & _MAN).T
-    bzT = b.T == 0.0                               # dfactor(·, 0) == 0
-    # grad side, transposed: (bn, bm)
-    sgT = (gi & _SIGN).T
-    gzT = gr.T == 0.0
-    gmgT = (gi & _MAG).T - _BIAS
+    # Contraction over N: the cotangent supplies columns (bm, bn), the B
+    # side rows of its transpose (bn, bkk). Zero tests become AND-masks
+    # (0 where dfactor(·, 0) == 0 or G == 0, else ~0).
+    g_side = (gi & _SIGN, jnp.where(gr == 0.0, 0, -1).astype(jnp.int32),
+              (gi & _MAG) - _BIAS)
+    biT = bi.T
+    b_side = (biT & _EXP, biT & _SIGN, biT & _MAN,
+              jnp.where(b == 0.0, 0, -1).astype(jnp.int32).T)
 
-    ng = bn // g
-    ebT = ebT.reshape(ng, g, bkk)
-    sbT = sbT.reshape(ng, g, bkk)
-    mbT = mbT.reshape(ng, g, bkk)
-    bzT = bzT.reshape(ng, g, bkk)
-    sgT = sgT.reshape(ng, g, bm)
-    gzT = gzT.reshape(ng, g, bm)
-    gmgT = gmgT.reshape(ng, g, bm)
-
-    part = None
-    for j in range(g):
+    def product(cols, rows):
+        sg, gzm, gmg = cols
+        eb, sb, mb, bzm = rows
         # carry 1{M_a + M_b >= 1} lands directly in the exponent-field bit
-        carry = (maf_a[None, :, :] + mbT[:, j, None, :]) & _MIN_NORM
-        magf = jnp.clip(ebT[:, j, None, :] + carry, _MIN_NORM, _MAX_EXPF)
-        mag = magf + gmgT[:, j, :, None]
+        carry = (maf_a + mb) & _MIN_NORM
+        magf = jnp.clip(eb + carry, _MIN_NORM, _MAX_EXPF)
+        mag = magf + gmg
         mag = jnp.where(mag < _MIN_NORM, 0, jnp.minimum(mag, _MAX_FINITE))
-        bits = (sbT[:, j, None, :] ^ sgT[:, j, :, None]) | mag
-        p = jax.lax.bitcast_convert_type(bits, jnp.float32)
-        zero = bzT[:, j, None, :] | gzT[:, j, :, None]
-        p = jnp.where(zero, 0.0, p)
-        part = p if part is None else part + p
-    acc_ref[...] += jnp.sum(part, axis=0)
+        bits = ((sb ^ sg) | mag) & (bzm & gzm)
+        return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
-    @pl.when(pl.program_id(3) == nn - 1)
+    j = pl.program_id(3)
+    _accumulate(acc_ref, _contract(g_side, b_side, product, g), j)
+
+    @pl.when(j == nn - 1)
     def _out():
         o_ref[0] = acc_ref[...]
 
@@ -270,5 +258,6 @@ def pam_exact_grad_a_batched(a, b, gr, *, bm: int, bn: int, bk: int, g: int,
         out_shape=jax.ShapeDtypeStruct((B, mp, kp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm_, bk_), jnp.float32)],
         interpret=interpret,
+        name="pam_exact_grad",
     )(a, b, gr)
     return out[:, :m, :k]

@@ -17,8 +17,10 @@ level (HomebrewNLP-Jax's fused-step / MaxText's donated-buffer posture).
 The leaf driver flattens a parameter leaf to a (rows·cols)-padded
 (R, cols) plane and runs a 1-D grid over row blocks; tile params resolve
 from ``kernels/autotune.py`` (op ``"pam_optim"``, keyed by the element
-count bucket). Scalars (t, lr, clip scale) ride in one (3,) f32 vector
-whose BlockSpec pins every grid step to the same block.
+count bucket). The step's f32 scalars (bias corrections, lr, lr ·̂ wd,
+clip scale — ``ref.pa_adamw_scalars``) ride in one (5,) vector whose
+BlockSpec pins every grid step to the same block; the kernel broadcasts
+each to the tile before any bit math (the TPU bitcasts vectors only).
 """
 from __future__ import annotations
 
@@ -29,40 +31,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import floatbits as _fb
+from repro.core.pam import ValueOps
 from .ref import pa_adamw_math
 
 
 def _kernel(s_ref, p_ref, g_ref, m_ref, v_ref, op_ref, om_ref, ov_ref, *,
-            b1, b2, eps, wd, apply_scale, fmt_name="f32"):
-    cdt = _fb.FORMATS[fmt_name].dtype
-    t, lr, scale = s_ref[0], s_ref[1], s_ref[2]
+            b1, b2, eps, apply_scale, fmt_name="f32"):
+    fmt = _fb.FORMATS[fmt_name]
+    cdt = fmt.dtype
+    shape = p_ref.shape
+    bc1, bc2, lr, lr_wd, scale = (jnp.full(shape, s_ref[i], jnp.float32)
+                                  for i in range(5))
     pf = p_ref[...].astype(cdt)
     g = g_ref[...].astype(cdt)
     m32 = m_ref[...].astype(cdt)             # bf16 moment decode (f32 mode)
     v32 = v_ref[...].astype(cdt)
-    new_p, m_new, v_new = pa_adamw_math(pf, g, m32, v32, t, lr, scale,
-                                        b1=b1, b2=b2, eps=eps, wd=wd,
-                                        apply_scale=apply_scale)
+    new_p, m_new, v_new = pa_adamw_math(pf, g, m32, v32, bc1, bc2, lr, lr_wd,
+                                        scale, b1=b1, b2=b2, eps=eps,
+                                        apply_scale=apply_scale,
+                                        ops=ValueOps(fmt.widened))
     op_ref[...] = new_p.astype(op_ref.dtype)
     om_ref[...] = m_new.astype(om_ref.dtype)  # bf16 moment encode
     ov_ref[...] = v_new.astype(ov_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "b1", "b2", "eps", "wd", "apply_scale", "rows", "cols", "interpret",
+    "b1", "b2", "eps", "apply_scale", "rows", "cols", "interpret",
     "fmt_name"))
-def pa_adamw_leaf_pallas(p, g, m, v, scalars, *, b1, b2, eps, wd,
-                         apply_scale, rows: int = 8, cols: int = 1024,
+def pa_adamw_leaf_pallas(p, g, m, v, scalars, *, b1, b2, eps, apply_scale,
+                         rows: int = 8, cols: int = 1024,
                          interpret: bool = True, fmt_name: str = "f32"):
     """Fused PA AdamW update of one parameter leaf.
 
     p: any shape/dtype; g: same shape (decoded to the compute format); m/v:
-    moment leaves (f32 or bf16); scalars: (3,) f32 = [t, lr, clip_scale].
-    Returns (new_p, new_m, new_v) with the input dtypes. Zero-padding is
-    inert: a padded element has g = m = v = p = 0, and the PA chain maps it
-    to 0. ``fmt_name="bf16"`` runs the whole chain in the int16 carrier:
-    ``pa_adamw_math``'s value ops dispatch on the decoded dtype, and the
-    gradient plane streams through HBM at bf16 width.
+    moment leaves (f32 or bf16); scalars: (5,) f32 = [bc1, bc2, lr,
+    lr ·̂ wd, clip_scale]. Returns (new_p, new_m, new_v) with the input
+    dtypes. Zero-padding is inert: a padded element has g = m = v = p = 0,
+    and the PA chain maps it to 0. ``fmt_name="bf16"`` runs the whole chain
+    in bf16 on the widened int32 carrier, and the gradient plane streams
+    through HBM at bf16 width.
     """
     gdt = jnp.float32 if fmt_name == "f32" else _fb.FORMATS[fmt_name].dtype
     shape, n = p.shape, p.size
@@ -87,10 +94,10 @@ def pa_adamw_leaf_pallas(p, g, m, v, scalars, *, b1, b2, eps, wd,
     rtot = npad // cols
 
     new_p, new_m, new_v = pl.pallas_call(
-        functools.partial(_kernel, b1=b1, b2=b2, eps=eps, wd=wd,
+        functools.partial(_kernel, b1=b1, b2=b2, eps=eps,
                           apply_scale=apply_scale, fmt_name=fmt_name),
         grid=(rtot // rows,),
-        in_specs=[pl.BlockSpec((3,), lambda i: (0,)),
+        in_specs=[pl.BlockSpec((5,), lambda i: (0,)),
                   pl.BlockSpec((rows, cols), lambda i: (i, 0)),
                   pl.BlockSpec((rows, cols), lambda i: (i, 0)),
                   pl.BlockSpec((rows, cols), lambda i: (i, 0)),
@@ -104,6 +111,7 @@ def pa_adamw_leaf_pallas(p, g, m, v, scalars, *, b1, b2, eps, wd,
         # donate the padded p/m/v planes onto their outputs (in-place update)
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="pa_adamw",
     )(scalars, pv, gv, mv, vv)
 
     def unplane(x, dt):

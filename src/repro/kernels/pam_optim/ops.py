@@ -4,9 +4,9 @@
 dispatches to when the PA optimizer is active: ``impl="pallas"`` drives the
 fused kernel leaf by leaf (flattened planes, donated buffers, tile params
 from the shared autotune registry); any other impl runs the jnp engine —
-the same ``pa_adamw_math`` mapped over leaves, bit-identical by
-construction. Scalar inputs (t, lr, clip scale) are computed once by the
-caller; hyperparameters are static and baked into the kernel.
+the same ``pa_adamw_math`` mapped over leaves. The step's scalars (bias
+corrections, lr, lr ·̂ wd, clip scale) are computed once here for both
+engines; hyperparameters are static and baked into the kernel.
 """
 from __future__ import annotations
 
@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from .. import autotune
 from .._backend import use_interpret
 from .kernel import pa_adamw_leaf_pallas
-from .ref import pa_adamw_leaf_ref
+from .ref import pa_adamw_leaf_ref, pa_adamw_scalars
 
 
 def tree_unzip3(out):
@@ -32,19 +32,21 @@ def pa_adamw_update(params, grads, m, v, t, lr, scale, *, b1, b2, eps,
     """Fused PA AdamW step over pytrees. ``scale`` is the traced clip scale
     or None (grad_clip == 0: gradients enter the chain unscaled, matching
     the value-level seed bit for bit). ``fmt="bf16"`` runs the elementwise
-    chain natively in the int16 carrier (both engines). Returns
+    chain natively in bf16 (both engines). Returns
     (new_params, new_m, new_v)."""
     apply_scale = scale is not None
     hyp = dict(b1=float(b1), b2=float(b2), eps=float(eps),
-               wd=float(weight_decay), apply_scale=apply_scale)
+               apply_scale=apply_scale)
     t = jnp.asarray(t, jnp.float32)
     lr = jnp.asarray(lr, jnp.float32)
     scale_ = jnp.float32(0) if scale is None else jnp.asarray(scale,
                                                               jnp.float32)
+    bc1, bc2, lr_wd = pa_adamw_scalars(t, lr, b1=float(b1), b2=float(b2),
+                                       wd=float(weight_decay))
 
     if impl == "pallas":
         interpret = use_interpret()
-        scalars = jnp.stack([t, lr, scale_])
+        scalars = jnp.stack([bc1, bc2, lr, lr_wd, scale_])
 
         def upd(p, g, mm, vv):
             rows, cols = autotune.tile_params("pam_optim", (p.size,),
@@ -55,7 +57,7 @@ def pa_adamw_update(params, grads, m, v, t, lr, scale, *, b1, b2, eps,
                                         **hyp)
     else:
         def upd(p, g, mm, vv):
-            return pa_adamw_leaf_ref(p, g, mm, vv, t, lr, scale_,
-                                     fmt_name=fmt, **hyp)
+            return pa_adamw_leaf_ref(p, g, mm, vv, bc1, bc2, lr, lr_wd,
+                                     scale_, fmt_name=fmt, **hyp)
 
     return tree_unzip3(jax.tree.map(upd, params, grads, m, v))

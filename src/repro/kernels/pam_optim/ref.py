@@ -2,18 +2,20 @@
 
 ``pa_adamw_math`` is the single elementwise definition of the fused
 piecewise-affine AdamW step (paper §2.6): clip-scale apply, m/v moment
-updates, ``paexp2``/``palog2`` bias correction, ``pasqrt``, ``padiv``, lr
-apply and decoupled weight decay — every multiplication/division/sqrt a PA
-op, every power-of-two scale an exact exponent add. Both execution engines
-call this exact function — the Pallas kernel traces it per VMEM tile
-(``kernel.py``), the jnp engine maps it over leaves — so the engines are
-bit-identical by construction, and both are bit-identical to the frozen
-value-level seed chain (``benchmarks/seed_reference.seed_pa_adamw_update``,
-the pre-fusion ``adamw_update`` PA branch), which used the same
-``pam_value``/``padiv_value`` compositions op for op.
+updates, ``pasqrt``, ``padiv``, lr apply and decoupled weight decay —
+every multiplication/division/sqrt a PA op, every power-of-two scale an
+exact exponent add; the ``paexp2``/``palog2`` bias corrections are O(1)
+scalars (``pa_adamw_scalars``). Both execution engines call this exact
+function — the Pallas kernel traces it per VMEM tile (``kernel.py``) with
+the format's int32-carrier ``ValueOps``, the jnp engine maps it over
+leaves with the native-carrier ones — so the engines agree bit for bit,
+and both are bit-identical to the frozen value-level seed chain
+(``benchmarks/seed_reference.seed_pa_adamw_update``, the pre-fusion
+``adamw_update`` PA branch), which used the same ``pam_value``/
+``padiv_value`` compositions op for op.
 
 The optimizer is value-level (never differentiated through), so the raw
-``*_value`` forwards are used directly — no ``custom_vjp`` wrappers.
+value forwards are used directly — no ``custom_vjp`` wrappers.
 """
 from __future__ import annotations
 
@@ -21,52 +23,57 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core import floatbits as _fb
-from repro.core.pam import (pam_value, padiv_value, paexp2_value,
-                            palog2_value, pasqrt_value)
+from repro.core.pam import (ValueOps, pam_value, paexp2_value,
+                            palog2_value)
 
 
-def pa_adamw_math(pf, g, m32, v32, t, lr, scale, *, b1, b2, eps, wd,
-                  apply_scale):
-    """One fused PA AdamW step on f32 operands; returns (new_p, m_new, v_new)
-    in f32 (caller encodes back to the storage dtypes).
+def pa_adamw_scalars(t, lr, *, b1, b2, wd):
+    """The O(1) scalar schedule of one step, in f32: bias corrections
+    ``1 - b^t`` with b^t = paexp2(t ·̂ palog2 b), plus ``lr ·̂ wd``. Both
+    engines compute it once per step, outside the per-element chain."""
+    bc1 = 1.0 - paexp2_value(pam_value(t, palog2_value(np.float32(b1))))
+    bc2 = 1.0 - paexp2_value(pam_value(t, palog2_value(np.float32(b2))))
+    return bc1, bc2, pam_value(lr, np.float32(wd))
 
-    ``t``/``lr``/``scale`` are traced scalars; ``b1``/``b2``/``eps``/``wd``
-    are static python floats baked in as f32 immediates. ``apply_scale`` is
-    static: the clip scale is a PAM when ``grad_clip > 0`` and entirely
-    absent otherwise (bit parity with the unscaled seed path — PAM by 1.0
-    would still flush denormal gradients).
+
+def pa_adamw_math(pf, g, m32, v32, bc1, bc2, lr, lr_wd, scale, *, b1, b2,
+                  eps, apply_scale, ops):
+    """One fused PA AdamW step on operands of one compute format; returns
+    (new_p, m_new, v_new) in that format (caller encodes back to the
+    storage dtypes). ``ops`` is the format's ``ValueOps``.
+
+    ``bc1``/``bc2``/``lr``/``lr_wd``/``scale`` come from
+    ``pa_adamw_scalars`` (f32, scalars or broadcast tiles); ``b1``/``b2``/
+    ``eps`` are static python floats baked in as immediates.
+    ``apply_scale`` is static: the clip scale is a PAM when
+    ``grad_clip > 0`` and entirely absent otherwise (bit parity with the
+    unscaled seed path — PAM by 1.0 would still flush denormal gradients).
     """
-    b1_ = np.float32(b1)
-    b2_ = np.float32(b2)
     if apply_scale:
-        g = pam_value(g, scale)
-    # Bias correction b^t = paexp2(t ·̂ palog2 b): O(1) scalar PA schedule,
-    # recomputed per tile in the kernel (same ops, same bits).
-    bc1 = 1.0 - paexp2_value(pam_value(t, palog2_value(b1_)))
-    bc2 = 1.0 - paexp2_value(pam_value(t, palog2_value(b2_)))
-    m_new = pam_value(b1_, m32) + pam_value(np.float32(1 - b1), g)
-    v_new = pam_value(b2_, v32) + pam_value(np.float32(1 - b2),
-                                            pam_value(g, g))
-    mhat = padiv_value(m_new, bc1)
-    vhat = padiv_value(v_new, bc2)
-    den = pasqrt_value(vhat)
-    upd = padiv_value(mhat, den + jnp.asarray(np.float32(eps), den.dtype))
-    new_p = pf - pam_value(lr, upd) - pam_value(pam_value(lr, np.float32(wd)),
-                                                pf)
+        g = ops.pam(g, scale)
+    m_new = ops.pam(np.float32(b1), m32) + ops.pam(np.float32(1 - b1), g)
+    v_new = ops.pam(np.float32(b2), v32) + ops.pam(np.float32(1 - b2),
+                                                  ops.pam(g, g))
+    mhat = ops.padiv(m_new, bc1)
+    vhat = ops.padiv(v_new, bc2)
+    den = ops.pasqrt(vhat)
+    upd = ops.padiv(mhat, den + jnp.asarray(np.float32(eps), den.dtype))
+    new_p = pf - ops.pam(lr, upd) - ops.pam(lr_wd, pf)
     return new_p, m_new, v_new
 
 
-def pa_adamw_leaf_ref(p, g, m, v, t, lr, scale, *, b1, b2, eps, wd,
-                      apply_scale, fmt_name="f32"):
+def pa_adamw_leaf_ref(p, g, m, v, bc1, bc2, lr, lr_wd, scale, *, b1, b2,
+                      eps, apply_scale, fmt_name="f32"):
     """jnp engine for one leaf: decode to the compute format, shared math,
     encode back to the storage dtypes (bf16 moments round-to-nearest-even,
     as the kernel's in-VMEM encode does). ``fmt_name="bf16"`` runs the
-    whole chain natively in the int16 carrier — every ``*_value`` op in
-    ``pa_adamw_math`` dispatches on the operand dtype."""
-    cdt = _fb.FORMATS[fmt_name].dtype
-    pf, g32, m32, v32 = (jnp.asarray(x).astype(cdt) for x in (p, g, m, v))
-    new_p, m_new, v_new = pa_adamw_math(pf, g32, m32, v32, t, lr, scale,
-                                        b1=b1, b2=b2, eps=eps, wd=wd,
-                                        apply_scale=apply_scale)
+    whole chain natively in the int16 carrier."""
+    fmt = _fb.FORMATS[fmt_name]
+    pf, g32, m32, v32 = (jnp.asarray(x).astype(fmt.dtype)
+                         for x in (p, g, m, v))
+    new_p, m_new, v_new = pa_adamw_math(pf, g32, m32, v32, bc1, bc2, lr,
+                                        lr_wd, scale, b1=b1, b2=b2, eps=eps,
+                                        apply_scale=apply_scale,
+                                        ops=ValueOps(fmt))
     return (new_p.astype(p.dtype), m_new.astype(m.dtype),
             v_new.astype(v.dtype))

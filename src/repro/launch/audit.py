@@ -38,6 +38,9 @@ from __future__ import annotations
 
 import os
 
+# A CPU-only tool: pin the platform before jax initialises, so it never
+# takes an accelerator from the process that owns it.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=4"
                            ).strip()

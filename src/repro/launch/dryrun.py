@@ -1,11 +1,13 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay the first statements in this module — jax
-locks the device count at first initialisation, and the production meshes
-need 512 placeholder CPU devices (2 pods x 16 x 16).
+The three lines above MUST stay the first statements in this module — jax
+locks the platform and the device count at first initialisation, the
+production meshes need 512 placeholder CPU devices (2 pods x 16 x 16), and
+a CPU-only tool must never take an accelerator.
 
 For each cell this:
   1. builds the full-scale model (PA mode "full", impl "hw": the PAM-MXU

@@ -33,13 +33,8 @@ def make_mesh(shape, axes):
                            "(dry-runs must set XLA_FLAGS first — see dryrun.py)")
     import numpy as np
     arr = np.asarray(devs[:need]).reshape(shape)
-    # jax.sharding.AxisType only exists on newer jax; Auto is the default
-    # there anyway, so older versions just omit the kwarg.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {}
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.sharding.Mesh(arr, tuple(axes), **kwargs)
+    return jax.sharding.Mesh(arr, tuple(axes),
+                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def host_mesh():

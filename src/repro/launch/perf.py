@@ -1,4 +1,5 @@
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"     # CPU-only tool: never take the chip
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 """Perf-iteration harness (§Perf): lower one cell with config overrides and

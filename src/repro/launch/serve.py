@@ -26,6 +26,7 @@ import jax
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.models import build_model
 from repro.serve import ContinuousEngine, Engine, Request, ServeConfig
+from .compile_cache import enable_compile_cache
 from .train import add_pa_args, build_pa
 
 
@@ -73,6 +74,7 @@ def main():
                     help="print tokens as they are produced")
     add_pa_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     pa = build_pa(args)
     cfg = (get_smoke_config(args.arch, pa=pa) if args.smoke

@@ -18,6 +18,7 @@ from repro.data import DataConfig
 from repro.models import build_model
 from repro.optim import OptConfig
 from repro.train import LoopConfig, TrainConfig, train
+from .compile_cache import enable_compile_cache
 
 
 def build_pa(args) -> PAConfig:
@@ -56,6 +57,7 @@ def main():
     ap.add_argument("--mesh-axes", default="pod,data,model")
     add_pa_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     pa = build_pa(args)
     cfg = (get_smoke_config(args.arch, pa=pa) if args.smoke

@@ -101,14 +101,16 @@ def leaf_digest(x: jax.Array, salt: int = 0) -> jax.Array:
     Each word is mixed with its index before folding, so transpositions
     and swaps change the digest, and ``fmix32``'s bijectivity guarantees
     any single bit flip in any word changes it too. The element count and
-    ``salt`` are folded in last (distinguishes shapes/dtypes that share a
-    word stream)."""
+    the dtype are folded in last (distinguishes shapes/dtypes that share a
+    word stream, e.g. a zero bf16 leaf and a zero f32 leaf)."""
     w = leaf_words(x)
     n = w.shape[0]
     idx = jax.lax.iota(jnp.uint32, n)
     h = _fmix32(w ^ _fmix32(idx ^ np.uint32(salt & _MASK32)))
     d = _xor_reduce(h, (0,))
-    return _fmix32(d ^ np.uint32(n & _MASK32))
+    dtype_salt = zlib.crc32(jnp.dtype(x.dtype).name.encode()) & _MASK32
+    return _fmix32(_fmix32(d ^ np.uint32(n & _MASK32))
+                   ^ np.uint32(dtype_salt))
 
 
 def tree_paths(tree: Any) -> List[str]:

@@ -125,7 +125,7 @@ def train(model: Model, opt_cfg: OptConfig, data_cfg: DataConfig,
                                         health=recovery is not None,
                                         fault_arg=use_fault_arg,
                                         record=recorder is not None)
-    step_fn = make_train_step(model, opt_cfg, train_cfg)
+    step_fn = make_train_step(model, opt_cfg, train_cfg, mesh=mesh)
 
     params = model.init(jax.random.PRNGKey(data_cfg.seed))
     opt_state = init_opt_state(params, opt_cfg)
@@ -140,9 +140,13 @@ def train(model: Model, opt_cfg: OptConfig, data_cfg: DataConfig,
     start_step = 0
     state_like = {"params": params, "opt": opt_state}
     shardings = None
+    place_batch = jnp.asarray
     if mesh is not None:
-        from repro.parallel.sharding import tree_shardings
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.parallel.sharding import batch_axes, tree_shardings
         from repro.optim import opt_state_meta
+        batch_sharding = NamedSharding(mesh, PartitionSpec(batch_axes(mesh)))
+        place_batch = lambda x: jax.device_put(x, batch_sharding)
         shardings = {"params": model.shardings(mesh),
                      "opt": tree_shardings(opt_state_meta(model.meta(), opt_cfg),
                                            mesh, model.cfg.rules)}
@@ -221,7 +225,7 @@ def train(model: Model, opt_cfg: OptConfig, data_cfg: DataConfig,
             if fault_plan.pop("preempt", step) is not None:
                 open(preempt_file, "w").close()
         d = data_index(step, skipped) if skipped else step
-        batch = jax.tree.map(jnp.asarray, data.batch(d))
+        batch = jax.tree.map(place_batch, data.batch(d))
         if use_fault_arg:
             params, opt_state, metrics = step_fn(
                 params, opt_state, batch, fault_plan.grad_fault(d))

@@ -15,6 +15,7 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import PAConfig
 from repro.core import floatbits as fb
@@ -22,6 +23,7 @@ from repro.core.floatbits import mantissa_round
 from repro.core.pam import pam_value
 from repro.models.registry import Model
 from repro.optim import OptConfig, adamw_update, init_opt_state
+from repro.parallel.sharding import batch_axes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,9 +56,34 @@ def _split_micro(batch, n):
     return jax.tree.map(sp, batch)
 
 
+def _mean_over(tree, n: int, pa: PAConfig):
+    """Average of ``n`` summed gradient trees. Tensor-shaped and feeding
+    the PA optimizer, so in PA mode it must not emit native multiplies: a
+    power-of-two count is an exponent shift (bit-identical to * 1/n except
+    that subnormal results flush to zero), anything else is a PAM by 1/n."""
+    if n == 1:
+        return tree
+    if pa.optimizer_is_pa and pa.impl != "hw":
+        if n & (n - 1) == 0:
+            shift = 1 - n.bit_length()          # 2^-log2(n), exact
+            return jax.tree.map(lambda g: fb.pow2_mul(g, shift), tree)
+        inv = np.float32(1.0 / n)
+        return jax.tree.map(lambda g: pam_value(g, inv), tree)
+    return jax.tree.map(lambda g: g * (1.0 / n), tree)
+
+
 def make_train_step(model: Model, opt_cfg: OptConfig,
-                    train_cfg: TrainConfig = TrainConfig()):
+                    train_cfg: TrainConfig = TrainConfig(), mesh=None):
+    """The jit-able train step. With a ``mesh`` the step runs under
+    ``shard_map`` with every mesh axis manual, because Pallas kernels
+    cannot be partitioned automatically: each device runs the whole step
+    on its shard of the batch (split over the data axes, replicated over
+    the rest), so every kernel sees per-device shapes, and the loss and
+    gradients are summed across the data axes (f32 ``psum``) and averaged
+    before the replicated optimizer update."""
     pa: PAConfig = model.cfg.pa
+    dp_axes = batch_axes(mesh) if mesh is not None else ()
+    n_dp = int(np.prod([mesh.shape[a] for a in dp_axes])) if dp_axes else 1
 
     def train_step(params, opt_state, batch, fault=None):
         if train_cfg.microbatches > 1:
@@ -72,24 +99,19 @@ def make_train_step(model: Model, opt_cfg: OptConfig,
             (loss_sum, gsum), _ = jax.lax.scan(acc, (jnp.float32(0), zeros), micro)
             n = train_cfg.microbatches
             # loss is a scalar metric: native mean (O(1) scalar, exempt from
-            # the multiplication-free audit). The gradient average is
-            # tensor-shaped and feeds the PA optimizer, so in PA mode it
-            # must not emit native multiplies: a power-of-two microbatch
-            # count is an exponent shift (bit-identical to * 1/n except
-            # that subnormal results flush to zero), anything else is a
-            # PAM by 1/n.
+            # the multiplication-free audit).
             loss = loss_sum * (1.0 / n)
-            if pa.optimizer_is_pa and pa.impl != "hw":
-                if n & (n - 1) == 0:
-                    shift = 1 - n.bit_length()          # 2^-log2(n), exact
-                    grads = jax.tree.map(lambda g: fb.pow2_mul(g, shift), gsum)
-                else:
-                    inv = np.float32(1.0 / n)
-                    grads = jax.tree.map(lambda g: pam_value(g, inv), gsum)
-            else:
-                grads = jax.tree.map(lambda g: g * (1.0 / n), gsum)
+            grads = _mean_over(gsum, n, pa)
         else:
             loss, grads = jax.value_and_grad(model.loss)(params, batch)
+
+        if n_dp > 1:
+            dtypes = jax.tree.map(lambda g: g.dtype, grads)
+            loss = jax.lax.psum(loss, dp_axes) * (1.0 / n_dp)
+            grads = _mean_over(jax.tree.map(
+                lambda g: jax.lax.psum(g.astype(jnp.float32), dp_axes),
+                grads), n_dp, pa)
+            grads = jax.tree.map(lambda g, dt: g.astype(dt), grads, dtypes)
 
         if train_cfg.grad_compress_bits is not None:
             grads = jax.tree.map(
@@ -125,10 +147,17 @@ def make_train_step(model: Model, opt_cfg: OptConfig,
                 {"params": params, "opt": opt_state})
         return params, opt_state, metrics
 
-    if train_cfg.fault_arg:
-        return train_step
-    # production signature unchanged when no fault plan is armed
-    return lambda params, opt_state, batch: train_step(params, opt_state, batch)
+    step = train_step
+    if not train_cfg.fault_arg:
+        # production signature unchanged when no fault plan is armed
+        step = lambda params, opt_state, batch: train_step(params, opt_state,
+                                                           batch)
+    if mesh is not None:
+        in_specs = (P(), P(), P(dp_axes)) + ((P(),) if train_cfg.fault_arg
+                                             else ())
+        step = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(), check_vma=False)
+    return step
 
 
 def make_eval_step(model: Model):

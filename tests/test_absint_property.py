@@ -1,8 +1,5 @@
 """Randomised soundness properties for the abstract domains (DESIGN.md §10).
 
-Runs under real Hypothesis when installed; in the container (which does
-not ship it) the seeded fallback driver ``tests/_proptest.py`` executes
-the same properties deterministically, so the suite no longer skips.
 ``tests/test_absint.py::test_interval_containment_seeded`` additionally
 keeps a deterministic slice of the containment property in tier-1.
 
@@ -20,10 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container fallback (seeded)
-    from _proptest import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.analysis import analyze_jaxpr  # noqa: E402
 from repro.analysis import domains as D  # noqa: E402

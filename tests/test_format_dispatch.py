@@ -123,8 +123,6 @@ class TestF32BitIdentity:
         assert p.padiv is pp._padiv
         assert p.paexp2 is pp._paexp2
         assert p.palog2 is pp._palog2
-        assert p.prep_tiles is pp._prep_tiles
-        assert p.grouped_pam_sum is pp._grouped_pam_sum
         assert p.pam_dot is pp._pam_dot
 
     def test_generic_builder_reproduces_seed_bits(self, rng):
@@ -333,3 +331,114 @@ class TestFormatDiscipline:
         band = max(pp.LMUL_REL_WORST, pp.LMUL_REL_PLUS) + 2.0 ** -22
         bound = band * (np.abs(a64) @ np.abs(b64))
         assert np.all(np.abs(got - true) <= bound + 1e-9)
+
+
+# ---------------------------------------------------------------------------
+# 5. Widened carriers: what the TPU kernels run (no 16-bit compares on v5e).
+# ---------------------------------------------------------------------------
+
+def _all_bf16():
+    """Every bf16 bit pattern: zeros, denormals, normals, infs, NaNs."""
+    return fb.floats(jnp.arange(-32768, 32768, dtype=jnp.int32),
+                     fb.BFLOAT16.widened)
+
+
+def _u16(x):
+    return np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16))
+
+
+def _bf16_pairs(rng, n=1 << 17):
+    """Random bit-pattern pairs plus every pair of a grid of edge values."""
+    edges = np.array([0x0000, 0x8000, 0x0001, 0x807F, 0x0080, 0x8080,
+                      0x3F80, 0xBF80, 0x3FC0, 0x7F7F, 0xFF7F, 0x7F00,
+                      0x0100, 0x7F80, 0xFF80, 0x7FC0, 0x4300, 0xC300],
+                     np.uint16)
+    ea, eb = np.meshgrid(edges, edges)
+    ra = rng.integers(0, 1 << 16, n).astype(np.uint16)
+    rb = rng.integers(0, 1 << 16, n).astype(np.uint16)
+    to = lambda u: jax.lax.bitcast_convert_type(jnp.asarray(u), jnp.bfloat16)
+    return (to(np.concatenate([ra, ea.ravel()])),
+            to(np.concatenate([rb, eb.ravel()])))
+
+
+class TestWidenedCarrier:
+    def test_widened_layout_round_trips_every_pattern(self):
+        w = fb.BFLOAT16.widened
+        assert (w.dtype, w.storage, w.carrier) == (jnp.bfloat16, jnp.int16,
+                                                   jnp.int32)
+        assert fb.FLOAT32.widened is fb.FLOAT32 and w.widened is w
+        for name in ("SIGN_MASK", "MAG_MASK", "EXP_MASK", "MAN_MASK",
+                     "BIAS_SHIFTED", "MIN_NORM", "MAX_FINITE", "INF_BITS",
+                     "ZERO_SENTINEL", "LMUL_OFFSET"):
+            assert int(getattr(w, name)) == int(getattr(fb.BFLOAT16, name))
+        x = _all_bf16()
+        np.testing.assert_array_equal(
+            np.asarray(fb.bits(x, w)), np.arange(-32768, 32768))
+        np.testing.assert_array_equal(
+            np.asarray(fb.bits(x, w)),
+            np.asarray(fb.bits(x, fb.BFLOAT16)).astype(np.int32))
+
+    @pytest.mark.parametrize("op", ["paexp2", "palog2", "pasqrt"])
+    def test_value_ops_unary_exhaustive(self, op):
+        """The IEEE-complete value ops on every bf16 input: int32 carrier
+        == int16 carrier, bit for bit (the fused PA-AdamW kernel's ops)."""
+        x = _all_bf16()
+        narrow = jax.jit(getattr(pam.ValueOps(fb.BFLOAT16), op))(x)
+        wide = jax.jit(getattr(pam.ValueOps(fb.BFLOAT16.widened), op))(x)
+        assert wide.dtype == narrow.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(_u16(wide), _u16(narrow))
+
+    @pytest.mark.parametrize("op", ["pam", "padiv"])
+    def test_value_ops_binary(self, rng, op):
+        a, b = _bf16_pairs(rng)
+        narrow = jax.jit(getattr(pam.ValueOps(fb.BFLOAT16), op))(a, b)
+        wide = jax.jit(getattr(pam.ValueOps(fb.BFLOAT16.widened), op))(a, b)
+        np.testing.assert_array_equal(_u16(wide), _u16(narrow))
+
+    @pytest.mark.parametrize("lmul", [False, True])
+    def test_kernel_prims_match_int16_carrier(self, rng, lmul):
+        """The kernels' prims (widened) against the same formulas on the
+        int16 carrier, over their contract: finite inputs, palog2 of
+        positives, and the grouped tile product."""
+        w = get_prims("bf16", lmul)
+        n16 = _build_prims(fb.BFLOAT16, lmul)
+        assert w.fmt.carrier == jnp.int32 and n16.fmt.carrier == jnp.int16
+        a, b = _bf16_pairs(rng)
+        fin = np.isfinite(np.asarray(a, np.float32)) & np.isfinite(
+            np.asarray(b, np.float32))
+        a, b = a[fin], b[fin]
+        for name in ("pam", "padiv"):
+            np.testing.assert_array_equal(
+                _u16(jax.jit(getattr(w, name))(a, b)),
+                _u16(jax.jit(getattr(n16, name))(a, b)), err_msg=name)
+        np.testing.assert_array_equal(_u16(jax.jit(w.paexp2)(a)),
+                                      _u16(jax.jit(n16.paexp2)(a)))
+        pos = jnp.abs(a)
+        np.testing.assert_array_equal(_u16(jax.jit(w.palog2)(pos)),
+                                      _u16(jax.jit(n16.palog2)(pos)))
+        x = _log_uniform(rng, 24 * 96, -8.0, 8.0,
+                         jnp.bfloat16).reshape(24, 96)
+        y = _log_uniform(rng, 96 * 40, -8.0, 8.0,
+                         jnp.bfloat16).reshape(96, 40)
+        dot = lambda p: jax.jit(lambda u, v: p.pam_dot(u, v, 8))(x, y)
+        np.testing.assert_array_equal(np.asarray(dot(w)),
+                                      np.asarray(dot(n16)))
+
+    def test_optim_family_engines_bit_equal_bf16(self, rng):
+        """bf16 compute: the kernel's widened chain equals the jnp engine's
+        int16 chain under jit (eager dispatch rounds bf16 intermediates
+        that a compiled program may keep at f32 precision)."""
+        from repro.kernels.pam_optim.ops import pa_adamw_update
+        p = {"w": _log_uniform(rng, 4096, -4.0, 2.0, jnp.bfloat16)}
+        g = {"w": _log_uniform(rng, 4096, -6.0, 0.0, jnp.float32)}
+        m = {"w": _log_uniform(rng, 4096, -8.0, -2.0, jnp.float32)}
+        v = {"w": jnp.abs(_log_uniform(rng, 4096, -12.0, -4.0, jnp.float32))}
+        outs = {impl: jax.jit(lambda p, g, m, v, impl=impl: pa_adamw_update(
+                    p, g, m, v, 3, 1e-3, jnp.float32(0.5), b1=0.9, b2=0.95,
+                    eps=1e-8, weight_decay=0.1, impl=impl, fmt="bf16"))(
+                    p, g, m, v)
+                for impl in ("jnp", "pallas")}
+        for a, b in zip(jax.tree_util.tree_leaves(outs["jnp"]),
+                        jax.tree_util.tree_leaves(outs["pallas"])):
+            np.testing.assert_array_equal(np.asarray(a).view(np.uint8),
+                                          np.asarray(b).view(np.uint8))

@@ -99,7 +99,6 @@ class TestShardingRules:
         assert isinstance(spec, P)
 
     def test_priority_kv_over_seq(self):
-        # make_mesh handles the AxisType kwarg across jax versions
         mesh = self._mesh()
         # kv divisible -> takes "model"; seq then can't reuse it
         spec = spec_for((2, 128, 16, 64),
@@ -138,3 +137,39 @@ class TestHloStats:
                                    (7 / 8) * 16 * 256 * 2)
         assert s["collective-permute"]["bytes"] == 16.0
         assert s["total_bytes"] > 0
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import REPO_CACHE_DIR, enable_compile_cache
+d = enable_compile_cache()
+if d != REPO_CACHE_DIR:
+    jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()
+print(d)
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "repo"])
+def test_compile_cache_location(tmp_path, env_dir):
+    """$JAX_COMPILATION_CACHE_DIR wins and receives the entries; without
+    it the cache is the fixed <repo>/.jax_cache (this test writes
+    nothing there)."""
+    import subprocess
+    import sys
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=os.path.join(root, "src"), JAX_PLATFORMS="cpu",
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    got, configured = out.stdout.split()
+    want = (str(tmp_path) if env_dir
+            else os.path.abspath(os.path.join(root, ".jax_cache")))
+    assert got == configured == want
+    if env_dir:
+        assert os.listdir(tmp_path), "no cache entry written"

@@ -266,9 +266,10 @@ class TestTunablesAndFallback:
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
     def test_interpret_backend_helper(self, monkeypatch):
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-        assert _backend.use_interpret() is True
-        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-        assert _backend.use_interpret() is False
-        monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+        """The platform alone decides: interpret off the TPU, compiled on
+        it, re-read at every call (no import-time freeze, no override)."""
         assert _backend.use_interpret() == (jax.default_backend() != "tpu")
+        monkeypatch.setattr(_backend.jax, "default_backend", lambda: "tpu")
+        assert _backend.use_interpret() is False
+        monkeypatch.setattr(_backend.jax, "default_backend", lambda: "cpu")
+        assert _backend.use_interpret() is True

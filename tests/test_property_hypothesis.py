@@ -1,19 +1,10 @@
-"""Property-based tests (hypothesis) for the system's core invariants.
-
-Runs under real ``hypothesis`` when installed (dev-only extra,
-requirements-dev.txt); otherwise the seeded fallback driver in
-``tests/_proptest.py`` executes the same properties deterministically —
-the suite no longer silently skips in the container.
-"""
+"""Property-based tests (hypothesis) for the system's core invariants."""
 import math
 
 import numpy as np
 import jax.numpy as jnp
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                       # container fallback (seeded)
-    from _proptest import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import pam_value, padiv_value, paexp2_value, palog2_value
 from repro.core import floatbits as fb

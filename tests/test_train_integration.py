@@ -101,3 +101,18 @@ def test_serve_greedy_consistent_with_forward(tmp_path):
     full, _ = model.logits(params, {"tokens": jnp.asarray(prompts)})
     first = np.asarray(jnp.argmax(full[:, -1], -1))
     np.testing.assert_array_equal(out[:, 0], first)
+
+
+@pytest.mark.parametrize("pa", [
+    PAConfig(mode="off"),
+    PAConfig(mode="full", deriv="approx", loss_deriv="exact"),
+])
+def test_mesh_training_matches_single_device(tmp_path, pa):
+    """``train(mesh=...)`` runs the step under ``shard_map`` (every mesh
+    axis manual, batch split over the data axes). On this process's one
+    device the mesh step must give the unmeshed step's losses."""
+    from repro.launch.mesh import host_mesh
+    cfg = TINY.replace(pa=pa)
+    _, plain = _run(tmp_path / "plain", cfg, steps=3)
+    _, meshed = _run(tmp_path / "mesh", cfg, steps=3, mesh=host_mesh())
+    np.testing.assert_allclose(meshed["loss"], plain["loss"], rtol=1e-6)
