@@ -8,7 +8,9 @@ TPU through the library calls the launchers make (``repro.launch.train``,
   python chip_smoke.py            one chip: 3 training steps each in modes
                                   off, matmul, full (f32) and full (bf16)
                                   on the Pallas kernels, then full on the
-                                  jnp engine as the reference; then greedy
+                                  jnp engine as the reference; PA-AdamW on
+                                  both engines in both formats against a
+                                  numpy reference; then greedy
                                   continuous-batching serving in full mode
                                   against the one-shot engine
   python chip_smoke.py --chips 4  full-mode Pallas training on a
@@ -37,7 +39,6 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 from repro.configs import get_config  # noqa: E402
 from repro.core import PAConfig  # noqa: E402
@@ -45,10 +46,11 @@ from repro.data import DataConfig  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
-from repro.optim import OptConfig, init_opt_state, opt_state_meta  # noqa: E402
-from repro.parallel.sharding import batch_axes, tree_shardings  # noqa: E402
+from repro.kernels.pam_optim.ops import pa_adamw_update  # noqa: E402
+from repro.kernels.pam_optim.ref import pa_adamw_numpy  # noqa: E402
+from repro.optim import OptConfig, init_opt_state  # noqa: E402
 from repro.serve import ContinuousEngine, Engine, Request, ServeConfig  # noqa: E402
-from repro.train import LoopConfig, TrainConfig, make_train_step, train  # noqa: E402
+from repro.train import LoopConfig, TrainConfig, jit_train_step, train  # noqa: E402
 
 ARCH = "smollm-135m"
 SEQ, BATCH, STEPS = 1024, 4, 3
@@ -108,32 +110,30 @@ def attention_engine(kernels, pa, fused):
 
 
 def peak_bytes(devices):
+    """Each device's peak bytes in use since the process started: the
+    phases run in one process, so a phase's number includes every phase
+    before it."""
     return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
             for d in devices]
 
 
-def abstract_step_args(model, opt, data, mesh):
-    """The shapes and placements ``train()`` hands its jitted step."""
+def step_args(model, opt, data, shardings, batch_sharding):
+    """Shapes of the arguments ``train()`` hands its jitted step, placed as
+    ``jit_train_step`` places them (None: the default device)."""
+    def placed(tree, sh):
+        if sh is None:
+            return tree
+        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=s), tree, sh)
+
     params = model.abstract()
     opt_state = jax.eval_shape(lambda p: init_opt_state(p, opt), params)
-    batch = {"tokens": jax.ShapeDtypeStruct((data.global_batch, data.seq_len),
-                                            np.int32),
-             "labels": jax.ShapeDtypeStruct((data.global_batch, data.seq_len),
-                                            np.int32),
-             "mask": jax.ShapeDtypeStruct((data.global_batch, data.seq_len),
-                                          np.bool_)}
-    if mesh is None:
-        return params, opt_state, batch
-
-    def place(tree, shardings):
-        return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=s), tree, shardings)
-
-    batch_sh = NamedSharding(mesh, P(batch_axes(mesh)))
-    return (place(params, model.shardings(mesh)),
-            place(opt_state, tree_shardings(opt_state_meta(model.meta(), opt),
-                                            mesh, model.cfg.rules)),
-            place(batch, {k: batch_sh for k in batch}))
+    batch = {k: jax.ShapeDtypeStruct((data.global_batch, data.seq_len), dt,
+                                     sharding=batch_sharding)
+             for k, dt in (("tokens", np.int32), ("labels", np.int32),
+                           ("mask", np.bool_))}
+    return (placed(params, shardings and shardings["params"]),
+            placed(opt_state, shardings and shardings["opt"]), batch)
 
 
 def train_phase(pa, *, fused, mesh=None, label=None):
@@ -144,14 +144,15 @@ def train_phase(pa, *, fused, mesh=None, label=None):
     data = DataConfig(vocab_size=cfg.vocab_size, seq_len=SEQ,
                       global_batch=BATCH)
     train_cfg = TrainConfig()
-    # The step train() jits, compiled ahead of time on the same shapes:
-    # its compile time and the kernels in its HLO. train() then loads it
-    # from the persistent compilation cache.
+    # The step train() jits (the same jit_train_step), compiled ahead of
+    # time on the same shapes and placements: its compile time and the
+    # kernels in its HLO. train() then loads it from the persistent
+    # compilation cache.
     t0 = time.perf_counter()
-    step = jax.jit(make_train_step(model, opt, train_cfg, mesh=mesh),
-                   donate_argnums=(0, 1))
-    compiled = step.lower(*abstract_step_args(model, opt, data,
-                                              mesh)).compile()
+    step, shardings, batch_sharding = jit_train_step(model, opt, train_cfg,
+                                                     mesh)
+    compiled = step.lower(*step_args(model, opt, data, shardings,
+                                     batch_sharding)).compile()
     compile_s = time.perf_counter() - t0
     calls = custom_calls(compiled)
     workdir = tempfile.mkdtemp(prefix="chip_smoke_")   # train() resumes
@@ -175,7 +176,7 @@ def train_phase(pa, *, fused, mesh=None, label=None):
         "step_s": [round(t, 4) for t in hist["step_time"]],
         "loss": hist["loss"],
         "tpu_custom_call": len(calls), "kernels": kernels,
-        "peak_bytes_in_use": peak_bytes(devices),
+        "process_peak_bytes_in_use": peak_bytes(devices),
     }
     check(len(hist["loss"]) == STEPS, f"{rec['label']}: ran "
           f"{len(hist['loss'])} of {STEPS} steps")
@@ -214,7 +215,46 @@ def one_chip():
     check(diffs[0] <= LOSS_RTOL, f"first-step loss full/pallas "
           f"{recs['full']['loss'][0]} vs full/jnp "
           f"{recs['full_jnp']['loss'][0]}: rel diff {diffs[0]:.3g}")
+    optim_phase()
     serve_phase()
+
+
+def optim_phase():
+    """PA-AdamW on one smollm-135m MLP leaf (576 x 1536: bf16 params and
+    gradients, f32 moments, as training holds them), in both formats, on
+    the compiled kernel and on the jnp engine: each must match the
+    independent numpy reference ``pa_adamw_numpy`` bit for bit."""
+    rng = np.random.default_rng(0)
+    shape = (576, 1536)
+    bf16 = jax.numpy.bfloat16
+    p = (rng.standard_normal(shape) * 0.05).astype(bf16)
+    g = (rng.standard_normal(shape) * 1e-3).astype(bf16)
+    m = (rng.standard_normal(shape) * 1e-4).astype(np.float32)
+    v = (rng.random(shape) * 1e-6 + 1e-9).astype(np.float32)
+    hyp = dict(b1=0.9, b2=0.98, eps=1e-8, weight_decay=1e-4)
+    t, lr, scale = 3.0, 1e-3, 0.75
+    for fmt in ("f32", "bf16"):
+        ref = pa_adamw_numpy(p, g, m, v, t, lr, scale, fmt_name=fmt, **hyp)
+        rec = {"phase": "optim", "fmt": fmt, "leaf": list(shape),
+               "what": "elements whose bits differ from the numpy reference"}
+        for impl in ("pallas", "jnp"):
+            out = jax.jit(lambda *a: pa_adamw_update(
+                *({"w": x} for x in a), t, lr, scale, impl=impl, fmt=fmt,
+                **hyp))(p, g, m, v)
+            rec[impl] = {n: differing_bits(o["w"], r) for n, o, r in
+                         zip(("p", "m", "v"), out, ref)}
+        emit(rec)
+        check(not any(rec["pallas"].values()) and not any(
+            rec["jnp"].values()), f"PA-AdamW ({fmt}) departs from the "
+            f"numpy reference: {rec}")
+
+
+def differing_bits(a, b):
+    """How many elements of ``a`` and ``b`` differ in their bit patterns."""
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape, (a.dtype, b.dtype)
+    u = {2: np.uint16, 4: np.uint32}[a.dtype.itemsize]
+    return int(np.count_nonzero(a.view(u) != b.view(u)))
 
 
 def serve_phase():
@@ -259,7 +299,7 @@ def serve_phase():
         "statuses": sorted(set(statuses.values())),
         "token_mismatch_vs_oneshot": mismatched,
         "tpu_custom_call": len(calls), "kernels": kernels,
-        "peak_bytes_in_use": peak_bytes(jax.devices()[:1]),
+        "process_peak_bytes_in_use": peak_bytes(jax.devices()[:1]),
     })
     check(len(out) == s["requests"] and all(
         len(v) == s["new_tokens"] for v in out.values()),
@@ -291,10 +331,12 @@ def four_chips():
     diffs = [rel(a, b) for a, b in zip(mesh_rec["loss"], one_rec["loss"])]
     emit({"phase": "check", "what": "mesh (data=4) vs one chip",
           "loss_rel_diff": diffs, "limit": LOSS_RTOL,
-          "mesh_peak_bytes_in_use": mesh_rec["peak_bytes_in_use"]})
+          "mesh_process_peak_bytes_in_use":
+              mesh_rec["process_peak_bytes_in_use"]})
     check(max(diffs) <= LOSS_RTOL, f"mesh vs one-chip loss rel diffs {diffs}")
-    check(all(b > 0 for b in mesh_rec["peak_bytes_in_use"]),
-          f"a mesh device held no memory: {mesh_rec['peak_bytes_in_use']}")
+    check(all(b > 0 for b in mesh_rec["process_peak_bytes_in_use"]),
+          f"a mesh device held no memory: "
+          f"{mesh_rec['process_peak_bytes_in_use']}")
     # Per-device kernels: under shard_map no PAM matmul sees the global
     # token count, which the one-chip program does.
     check(BATCH * SEQ in one_rec["pam_matmul_rows"]

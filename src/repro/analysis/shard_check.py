@@ -1,6 +1,6 @@
 """Multi-device audit checks (layer 4 support, DESIGN.md §9): prove the
 zero-tensor-multiply invariant survives ``shard_map`` collectives —
-the data-parallel train step's gradient psum, and a slot-sharded decode.
+gradient psum and the FSDP-style norm all-reduce (ROADMAP item 1).
 
 This module FORCES a 4-device host platform at import time (the flag must
 be set before the first jax initialisation), so it must run in its own
@@ -16,11 +16,11 @@ it as a subprocess; ``launch.audit`` (which forces the same flag) imports
 Checks (all on the tiny full-PA decoder used by the train-step audit
 gates):
 
-  ``train_dp``        — the library's data-parallel train step
-      (``train.make_train_step`` with a 4-way data mesh, the path
-      ``train(mesh=...)`` runs): per-shard value_and_grad under
-      ``shard_map``, f32 gradient psum, exact pow2 mean over shards
-      (4 devices = exponent shift), then the fused PA-AdamW update.
+  ``train_dp``        — data-parallel train step under ``shard_map``
+      over a 4-way mesh: per-shard value_and_grad, gradient psum, exact
+      pow2 mean over shards (4 devices = exponent shift), a PA
+      partial-norm all-reduce (per-shard PAM sum-of-squares -> scalar
+      psum -> O(1) scalar sqrt), then the fused PA-AdamW update.
   ``train_dp_health`` — same step with the bit-level non-finite sentinel
       folded in (integer exponent-field compares must stay exempt under
       collectives too).
@@ -84,13 +84,12 @@ def collective_count(jaxpr) -> int:
 
 
 def _train_dp(health: bool):
-    """(jaxpr, run_thunk) for the library's data-parallel train step:
-    ``make_train_step`` on a 4-way data mesh (``shard_map``, f32 gradient
-    and loss psums, exact pow2 mean over the 4 shards)."""
+    """(jaxpr, run_thunk) for the shard_map data-parallel train step."""
+    from repro.core import floatbits as fb
+    from repro.core.pam import pam_value
     from repro.data import DataConfig, SyntheticLM
     from repro.models import build_model
-    from repro.optim import OptConfig, init_opt_state
-    from repro.train import TrainConfig, make_train_step
+    from repro.optim import OptConfig, adamw_update, init_opt_state
 
     model = build_model(_tiny_cfg())
     opt_cfg = OptConfig(peak_lr=3e-3, warmup_steps=5, total_steps=30)
@@ -99,8 +98,32 @@ def _train_dp(health: bool):
     data = SyntheticLM(DataConfig(vocab_size=64, seq_len=32, global_batch=8,
                                   seed=1))
     batch = jax.tree.map(jnp.asarray, data.batch(0))
-    step = make_train_step(model, opt_cfg, TrainConfig(health=health),
-                           mesh=_mesh())
+
+    def dp_step(params, opt_state, batch):
+        loss, g_local = jax.value_and_grad(model.loss)(params, batch)
+        # FSDP-style norm all-reduce: per-shard PAM partial sum of squares,
+        # ONE scalar psum, sqrt on the O(1) scalar (audit-exempt).
+        local_sq = sum(jnp.sum(pam_value(x, x))
+                       for x in jax.tree.leaves(g_local))
+        dp_norm = jnp.sqrt(jax.lax.psum(local_sq, "data"))
+        # Gradient all-reduce, then mean over 4 shards = exact exponent
+        # shift (pow2_mul, the paper's "pow2 scales are exact" rule).
+        g = jax.tree.map(lambda x: jax.lax.psum(x, "data"), g_local)
+        g = jax.tree.map(lambda x: fb.pow2_mul(x, -2), g)
+        loss = fb.pow2_mul(jax.lax.psum(loss, "data"), -2)
+        params, opt_state, metrics = adamw_update(params, g, opt_state,
+                                                  opt_cfg, pa=model.cfg.pa)
+        metrics["loss"] = loss
+        metrics["dp_grad_norm"] = dp_norm
+        if health:
+            from repro.resilience.detectors import nonfinite_count
+            metrics["nonfinite"] = nonfinite_count(
+                (loss, metrics["grad_norm"], params))
+        return params, opt_state, metrics
+
+    step = jax.shard_map(dp_step, mesh=_mesh(),
+                         in_specs=(P(), P(), P("data")),
+                         out_specs=(P(), P(), P()), check_vma=False)
     jaxpr = jax.make_jaxpr(step)(params, opt_state, batch)
     run = lambda: jax.block_until_ready(jax.jit(step)(params, opt_state,
                                                       batch))

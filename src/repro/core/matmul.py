@@ -163,8 +163,7 @@ def _prep_operands(a, b, fmt=fb.FLOAT32, lmul: bool = False):
     if kp != k:
         a = jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, kp - k)])
         b = jnp.pad(b, [(0, 0)] * (b.ndim - 2) + [(0, kp - k), (0, 0)])
-    ai = jax.lax.bitcast_convert_type(a, fmt.carrier)
-    bi = jax.lax.bitcast_convert_type(b, fmt.carrier)
+    ai, bi = fb.bits(a, fmt), fb.bits(b, fmt)
     # f32 zero tests are FLOAT compares: under flush-to-zero arithmetic (CPU
     # and TPU) denormal inputs equal 0.0, matching pam_value's semantics.
     # (Narrow carriers use the exponent-field test — see _zero_mask.)
@@ -201,7 +200,7 @@ def _grouped_pam_sum(saT, amT, sb, bmg, bzM, g, fmt=fb.FLOAT32):
         mag = jnp.where(mag < fmt.MIN_NORM, 0, jnp.minimum(mag, fmt.MAX_FINITE))
         mag = mag & bzM[..., :, j, None, :]               # PAM(a, ±0) = ±0
         bits = (saT[..., :, j, :, None] ^ sb[..., :, j, None, :]) | mag
-        p = jax.lax.bitcast_convert_type(bits, fmt.dtype).astype(jnp.float32)
+        p = fb.floats(bits, fmt).astype(jnp.float32)
         part = p if part is None else part + p
     return jnp.sum(part, axis=-3)
 

@@ -239,6 +239,24 @@ class ValueOps:
     def pasqrt(self, a):
         return _pasqrt_value(a, self.fmt)
 
+    def round(self, x):
+        """The result ``x`` of a float add or subtract, rounded to the
+        format (to nearest, ties to even) by integer ops on its f32 bits.
+        A compiler may keep a narrow format's intermediates in f32 (XLA
+        does, on the CPU and the TPU) and a Mosaic kernel cannot reduce
+        precision, so this pins the rounding the PA definition has. Exact
+        for bf16, whose exponent range is f32's; the identity for f32."""
+        fmt = self.fmt
+        if fmt.width == 32:
+            return x
+        shift = 23 - fmt.man_bits
+        xf = jnp.asarray(x).astype(jnp.float32)
+        i = jax.lax.bitcast_convert_type(xf, jnp.int32)
+        i = ((i + np.int32((1 << (shift - 1)) - 1) + ((i >> shift) & 1))
+             & np.int32(-(1 << shift)))
+        r = jax.lax.bitcast_convert_type(i, jnp.float32)
+        return jnp.where(jnp.isnan(xf), xf, r).astype(fmt.dtype)
+
 
 # -- Exact-derivative scale factors (all signed powers of two) --------------
 

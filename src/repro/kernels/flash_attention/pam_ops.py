@@ -35,7 +35,7 @@ from repro.core.matmul import _pam_matmul_value
 from repro.core.pam import pam_value, padiv_value, paexp2_value
 
 from .. import autotune
-from .._backend import use_interpret
+from .._backend import per_device, use_interpret
 from ..pa_prims import _LOG2E, _LN2
 from . import pam_kernel as _pk
 
@@ -186,17 +186,26 @@ def _build(causal: bool, window, scale, impl: str, bq: int, bk: int, g: int,
            fmt_name: str = "f32"):
     dt = _fb.FORMATS[fmt_name].dtype
     if impl == "pallas":
+        # (B*H) rows split over a mesh's data axes: a contiguous block of
+        # query heads keeps its KV heads (b -> b // rep) on the same device.
+        fwd_k = functools.partial(
+            _pk.pam_flash_attention_fwd_bh, causal=causal, window=window,
+            scale=scale, bq=bq, bk=bk, g=g, interpret=interpret,
+            fmt_name=fmt_name)
+        bwd_k = functools.partial(
+            _pk.pam_flash_attention_bwd_bh, causal=causal, window=window,
+            scale=scale, bq=bbq, bk=bbk, g=bg, interpret=interpret,
+            fmt_name=fmt_name)
+
         def fwd_fn(q, k, v, qpos, kpos):
-            return _pk.pam_flash_attention_fwd_bh(
-                q, k, v, qpos, kpos, causal=causal, window=window,
-                scale=scale, bq=bq, bk=bk, g=g, interpret=interpret,
-                fmt_name=fmt_name)
+            return per_device(fwd_k, q, k, v, qpos, kpos,
+                              split=(True, True, True, False, False),
+                              out_split=(True, True, True))
 
         def bwd_fn(q, k, v, qpos, kpos, o, m, l, do):
-            return _pk.pam_flash_attention_bwd_bh(
-                q, k, v, qpos, kpos, o, m, l, do, causal=causal,
-                window=window, scale=scale, bq=bbq, bk=bbk, g=bg,
-                interpret=interpret, fmt_name=fmt_name)
+            return per_device(bwd_k, q, k, v, qpos, kpos, o, m, l, do,
+                              split=(True,) * 3 + (False,) * 2 + (True,) * 4,
+                              out_split=(True, True, True))
     else:
         fwd_jit = jax.jit(functools.partial(
             _jnp_fwd, causal=causal, window=window, scale=scale, bc=bk,

@@ -16,11 +16,13 @@ vs CPU interpret) is evaluated lazily per call via ``kernels._backend``.
 """
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
 
 from repro.core import floatbits as _fb
 
-from .._backend import use_interpret
+from .._backend import per_device, use_interpret
 from . import kernel as _k
 
 
@@ -54,6 +56,16 @@ def _fold_batches(a, b):
     return flat(a), flat(b), batch
 
 
+def _shares_rows(out_shape, *operands):
+    """Split flags for ``per_device``: which (operand, is_row_operand) pairs
+    share the output's leading dim, so that dividing it divides the call
+    exactly. A row operand shares it whenever its rank and leading size
+    are the output's; any other operand only as a batch dim (rank >= 3)."""
+    return tuple(len(out_shape) >= 2 and x.ndim == len(out_shape)
+                 and x.shape[0] == out_shape[0] and (rows or x.ndim >= 3)
+                 for x, rows in operands)
+
+
 def pam_matmul(a, b, *, bm: int | None = None, bn: int | None = None,
                bk: int | None = None, g: int | None = None,
                fmt_name: str | None = None, lmul: bool = False):
@@ -69,8 +81,17 @@ def pam_matmul(a, b, *, bm: int | None = None, bn: int | None = None,
     dt = _fb.FORMATS[fmt_name].dtype
     a = jnp.asarray(a, dt)
     b = jnp.asarray(b, dt)
-    interpret = use_interpret()
+    out_shape = (a.shape[:-1] + b.shape[-1:] if b.ndim == 2 else
+                 jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
+                 + (a.shape[-2], b.shape[-1]))
+    split = _shares_rows(out_shape, (a, True), (b, False))
+    return per_device(functools.partial(
+        _pam_matmul_local, bm=bm, bn=bn, bk=bk, g=g, fmt_name=fmt_name,
+        lmul=lmul), a, b, split=split, out_split=any(split))
 
+
+def _pam_matmul_local(a, b, *, bm, bn, bk, g, fmt_name, lmul):
+    interpret = use_interpret()
     if b.ndim == 2:
         # collapse leading dims into M (a 1D a collapses to M=1, matching
         # jnp.matmul's vector-matrix semantics): single 2D launch
@@ -112,6 +133,15 @@ def pam_exact_grad_a(a, b, gr, *, bm: int | None = None,
     a = jnp.asarray(a, jnp.float32)
     b = jnp.asarray(b, jnp.float32)
     gr = jnp.asarray(gr, jnp.float32)
+    out_shape = (jnp.broadcast_shapes(a.shape[:-2], b.shape[:-2])
+                 + a.shape[-2:])
+    split = _shares_rows(out_shape, (a, True), (b, False), (gr, True))
+    return per_device(functools.partial(
+        _exact_grad_a_local, bm=bm, bn=bn, bk=bk, g=g), a, b, gr,
+        split=split, out_split=any(split))
+
+
+def _exact_grad_a_local(a, b, gr, *, bm, bn, bk, g):
     interpret = use_interpret()
     a3, b3, batch = _fold_batches(a, b)
     m, k, n = a3.shape[-2], a3.shape[-1], b3.shape[-1]

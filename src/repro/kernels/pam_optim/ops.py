@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import autotune
-from .._backend import use_interpret
+from .._backend import per_device, use_interpret
 from .kernel import pa_adamw_leaf_pallas
 from .ref import pa_adamw_leaf_ref, pa_adamw_scalars
 
@@ -48,13 +48,22 @@ def pa_adamw_update(params, grads, m, v, t, lr, scale, *, b1, b2, eps,
         interpret = use_interpret()
         scalars = jnp.stack([bc1, bc2, lr, lr_wd, scale_])
 
-        def upd(p, g, mm, vv):
+        def leaf(p, g, mm, vv, scalars):
             rows, cols = autotune.tile_params("pam_optim", (p.size,),
                                               interpret, fmt)
             return pa_adamw_leaf_pallas(p, g, mm, vv, scalars,
                                         rows=int(rows), cols=int(cols),
                                         interpret=interpret, fmt_name=fmt,
                                         **hyp)
+
+        def upd(p, g, mm, vv):
+            # Elementwise: on a mesh each device updates its slice of the
+            # flattened leaf, whatever the leaf's own placement.
+            flat = [x.reshape(-1) for x in (p, g, mm, vv)]
+            out = per_device(leaf, *flat, scalars,
+                             split=(True,) * 4 + (False,),
+                             out_split=(True,) * 3, over="all")
+            return tuple(o.reshape(p.shape) for o in out)
     else:
         def upd(p, g, mm, vv):
             return pa_adamw_leaf_ref(p, g, mm, vv, bc1, bc2, lr, lr_wd,

@@ -1,5 +1,5 @@
 from .step import TrainConfig, make_train_step, make_eval_step
-from .loop import LoopConfig, train, straggler_check
+from .loop import LoopConfig, jit_train_step, train, straggler_check
 
 __all__ = ["TrainConfig", "make_train_step", "make_eval_step", "LoopConfig",
-           "train", "straggler_check"]
+           "jit_train_step", "train", "straggler_check"]

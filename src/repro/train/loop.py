@@ -90,6 +90,30 @@ def _note_restore_skipped(ckpt, history, log):
             f"{skipped} — integrity failures recorded in history")
 
 
+def jit_train_step(model: Model, opt_cfg: OptConfig,
+                   train_cfg: TrainConfig = TrainConfig(), mesh=None):
+    """The jitted step ``train()`` runs and the placements of its
+    arguments: ``(step_fn, shardings, batch_sharding)``. Without a mesh
+    both placements are None (the default device). On a mesh the params
+    and optimizer state keep their model placements (tensor, expert and
+    FSDP shardings from the model's axis rules) from step to step, and
+    the batch is split over the data axes."""
+    step_fn = make_train_step(model, opt_cfg, train_cfg, mesh=mesh)
+    if mesh is None:
+        return jax.jit(step_fn, donate_argnums=(0, 1)), None, None
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.parallel.sharding import batch_axes, tree_shardings
+    from repro.optim import opt_state_meta
+    shardings = {"params": model.shardings(mesh),
+                 "opt": tree_shardings(opt_state_meta(model.meta(), opt_cfg),
+                                       mesh, model.cfg.rules)}
+    step_fn = jax.jit(step_fn, donate_argnums=(0, 1),
+                      out_shardings=(shardings["params"], shardings["opt"],
+                                     NamedSharding(mesh, PartitionSpec())))
+    return (step_fn, shardings,
+            NamedSharding(mesh, PartitionSpec(batch_axes(mesh))))
+
+
 def train(model: Model, opt_cfg: OptConfig, data_cfg: DataConfig,
           workdir: str, loop_cfg: LoopConfig = LoopConfig(),
           train_cfg: TrainConfig = TrainConfig(),
@@ -125,7 +149,8 @@ def train(model: Model, opt_cfg: OptConfig, data_cfg: DataConfig,
                                         health=recovery is not None,
                                         fault_arg=use_fault_arg,
                                         record=recorder is not None)
-    step_fn = make_train_step(model, opt_cfg, train_cfg, mesh=mesh)
+    step_fn, shardings, batch_sharding = jit_train_step(model, opt_cfg,
+                                                        train_cfg, mesh)
 
     params = model.init(jax.random.PRNGKey(data_cfg.seed))
     opt_state = init_opt_state(params, opt_cfg)
@@ -139,20 +164,11 @@ def train(model: Model, opt_cfg: OptConfig, data_cfg: DataConfig,
 
     start_step = 0
     state_like = {"params": params, "opt": opt_state}
-    shardings = None
     place_batch = jnp.asarray
     if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec
-        from repro.parallel.sharding import batch_axes, tree_shardings
-        from repro.optim import opt_state_meta
-        batch_sharding = NamedSharding(mesh, PartitionSpec(batch_axes(mesh)))
         place_batch = lambda x: jax.device_put(x, batch_sharding)
-        shardings = {"params": model.shardings(mesh),
-                     "opt": tree_shardings(opt_state_meta(model.meta(), opt_cfg),
-                                           mesh, model.cfg.rules)}
         params = jax.tree.map(jax.device_put, params, shardings["params"])
         opt_state = jax.tree.map(jax.device_put, opt_state, shardings["opt"])
-    step_fn = jax.jit(step_fn, donate_argnums=(0, 1))
 
     history = _fresh_history()
     latest = ckpt.latest_step()

@@ -15,7 +15,6 @@ from typing import Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from repro.core import PAConfig
 from repro.core import floatbits as fb
@@ -23,7 +22,6 @@ from repro.core.floatbits import mantissa_round
 from repro.core.pam import pam_value
 from repro.models.registry import Model
 from repro.optim import OptConfig, adamw_update, init_opt_state
-from repro.parallel.sharding import batch_axes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,34 +54,14 @@ def _split_micro(batch, n):
     return jax.tree.map(sp, batch)
 
 
-def _mean_over(tree, n: int, pa: PAConfig):
-    """Average of ``n`` summed gradient trees. Tensor-shaped and feeding
-    the PA optimizer, so in PA mode it must not emit native multiplies: a
-    power-of-two count is an exponent shift (bit-identical to * 1/n except
-    that subnormal results flush to zero), anything else is a PAM by 1/n."""
-    if n == 1:
-        return tree
-    if pa.optimizer_is_pa and pa.impl != "hw":
-        if n & (n - 1) == 0:
-            shift = 1 - n.bit_length()          # 2^-log2(n), exact
-            return jax.tree.map(lambda g: fb.pow2_mul(g, shift), tree)
-        inv = np.float32(1.0 / n)
-        return jax.tree.map(lambda g: pam_value(g, inv), tree)
-    return jax.tree.map(lambda g: g * (1.0 / n), tree)
-
-
 def make_train_step(model: Model, opt_cfg: OptConfig,
                     train_cfg: TrainConfig = TrainConfig(), mesh=None):
-    """The jit-able train step. With a ``mesh`` the step runs under
-    ``shard_map`` with every mesh axis manual, because Pallas kernels
-    cannot be partitioned automatically: each device runs the whole step
-    on its shard of the batch (split over the data axes, replicated over
-    the rest), so every kernel sees per-device shapes, and the loss and
-    gradients are summed across the data axes (f32 ``psum``) and averaged
-    before the replicated optimizer update."""
+    """The jit-able train step. With a ``mesh`` the step is traced under
+    that mesh (``jax.sharding.use_abstract_mesh``) and stays automatically
+    partitioned; the mesh only tells the kernel wrappers where to place
+    their Pallas launches, which cannot be partitioned automatically
+    (``kernels._backend.per_device``)."""
     pa: PAConfig = model.cfg.pa
-    dp_axes = batch_axes(mesh) if mesh is not None else ()
-    n_dp = int(np.prod([mesh.shape[a] for a in dp_axes])) if dp_axes else 1
 
     def train_step(params, opt_state, batch, fault=None):
         if train_cfg.microbatches > 1:
@@ -99,19 +77,24 @@ def make_train_step(model: Model, opt_cfg: OptConfig,
             (loss_sum, gsum), _ = jax.lax.scan(acc, (jnp.float32(0), zeros), micro)
             n = train_cfg.microbatches
             # loss is a scalar metric: native mean (O(1) scalar, exempt from
-            # the multiplication-free audit).
+            # the multiplication-free audit). The gradient average is
+            # tensor-shaped and feeds the PA optimizer, so in PA mode it
+            # must not emit native multiplies: a power-of-two microbatch
+            # count is an exponent shift (bit-identical to * 1/n except
+            # that subnormal results flush to zero), anything else is a
+            # PAM by 1/n.
             loss = loss_sum * (1.0 / n)
-            grads = _mean_over(gsum, n, pa)
+            if pa.optimizer_is_pa and pa.impl != "hw":
+                if n & (n - 1) == 0:
+                    shift = 1 - n.bit_length()          # 2^-log2(n), exact
+                    grads = jax.tree.map(lambda g: fb.pow2_mul(g, shift), gsum)
+                else:
+                    inv = np.float32(1.0 / n)
+                    grads = jax.tree.map(lambda g: pam_value(g, inv), gsum)
+            else:
+                grads = jax.tree.map(lambda g: g * (1.0 / n), gsum)
         else:
             loss, grads = jax.value_and_grad(model.loss)(params, batch)
-
-        if n_dp > 1:
-            dtypes = jax.tree.map(lambda g: g.dtype, grads)
-            loss = jax.lax.psum(loss, dp_axes) * (1.0 / n_dp)
-            grads = _mean_over(jax.tree.map(
-                lambda g: jax.lax.psum(g.astype(jnp.float32), dp_axes),
-                grads), n_dp, pa)
-            grads = jax.tree.map(lambda g, dt: g.astype(dt), grads, dtypes)
 
         if train_cfg.grad_compress_bits is not None:
             grads = jax.tree.map(
@@ -152,12 +135,13 @@ def make_train_step(model: Model, opt_cfg: OptConfig,
         # production signature unchanged when no fault plan is armed
         step = lambda params, opt_state, batch: train_step(params, opt_state,
                                                            batch)
-    if mesh is not None:
-        in_specs = (P(), P(), P(dp_axes)) + ((P(),) if train_cfg.fault_arg
-                                             else ())
-        step = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
-                             out_specs=P(), check_vma=False)
-    return step
+    if mesh is None:
+        return step
+
+    def step_on_mesh(*args):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step(*args)
+    return step_on_mesh
 
 
 def make_eval_step(model: Model):

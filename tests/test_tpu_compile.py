@@ -28,7 +28,7 @@ BH, BKV, S, DH = 18, 6, 1024, 64          # B=2 x (9 query, 3 KV) heads
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -39,8 +39,13 @@ def one_chip():
     # persistent cache; keep it out of any cache the process has on.
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _compile(one_chip, fn, *shapes):
@@ -136,3 +141,59 @@ def test_pam_eltwise(one_chip, fmt, op):
     else:
         _compile(one_chip, lambda a, b: eltwise_binary(
             a, b, op=op, interpret=False, fmt_name=fmt), shape, shape)
+
+
+def test_mesh_train_step(v5e_2x2, monkeypatch):
+    """The full-PA Pallas train step of smollm-135m (depth cut to 2 layers)
+    at seq 1024 x batch 4 on a (data=2, model=2) mesh of the four chips.
+    Every kernel must compile inside its shard_map, on its data shard's
+    rows — 2048 tokens per PAM matmul, 2 x 9 heads of attention — while the
+    tensor-parallel weights stay sharded over ``model``."""
+    import dataclasses
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh
+    from repro.configs import get_config
+    from repro.core import PAConfig
+    from repro.kernels.flash_attention import pam_ops
+    from repro.kernels.pam_matmul import ops as mm_ops
+    from repro.kernels.pam_optim import ops as optim_ops
+    from repro.models import build_model
+    from repro.optim import OptConfig, init_opt_state
+    from repro.train import TrainConfig, jit_train_step
+
+    for mod in (mm_ops, pam_ops, optim_ops):       # what the chip runs
+        monkeypatch.setattr(mod, "use_interpret", lambda: False)
+    mesh = Mesh(np.array(v5e_2x2).reshape(2, 2), ("data", "model"))
+    cfg = dataclasses.replace(
+        get_config("smollm-135m", pa=PAConfig(mode="full", impl="pallas"),
+                   attn_fused_pam=True), n_layers=2)
+    model = build_model(cfg)
+    opt = OptConfig(peak_lr=3e-3, warmup_steps=1, total_steps=3)
+    step, shardings, batch_sharding = jit_train_step(model, opt,
+                                                     TrainConfig(), mesh)
+    placed = lambda tree, sh: jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, sh)
+    params = model.abstract()
+    opt_state = jax.eval_shape(lambda p: init_opt_state(p, opt), params)
+    batch = {k: jax.ShapeDtypeStruct((4, S), dt, sharding=batch_sharding)
+             for k, dt in (("tokens", jnp.int32), ("labels", jnp.int32),
+                           ("mask", jnp.bool_))}
+    assert any("model" in str(s.spec)
+               for s in jax.tree.leaves(shardings["params"]))
+    text = step.lower(placed(params, shardings["params"]),
+                      placed(opt_state, shardings["opt"]),
+                      batch).compile().as_text()
+    shapes = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.search(r'op_name="[^"]*/(\w+)/pallas_call"', line)
+            out = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]*)\]", line)
+            shapes.setdefault(name.group(1), set()).add(
+                tuple(int(d) for d in out.group(1).split(",")))
+    assert {"pam_matmul", "pam_attention_fwd", "pam_attention_dq",
+            "pam_attention_dkv", "pa_adamw"} <= set(shapes)
+    assert max(s[1] for s in shapes["pam_matmul"]) == 2048
+    assert {s[0] for s in shapes["pam_attention_fwd"]} == {BH}

@@ -108,9 +108,9 @@ def test_serve_greedy_consistent_with_forward(tmp_path):
     PAConfig(mode="full", deriv="approx", loss_deriv="exact"),
 ])
 def test_mesh_training_matches_single_device(tmp_path, pa):
-    """``train(mesh=...)`` runs the step under ``shard_map`` (every mesh
-    axis manual, batch split over the data axes). On this process's one
-    device the mesh step must give the unmeshed step's losses."""
+    """``train(mesh=...)`` places params, optimizer state and batches on
+    the mesh and traces the step under it. On this process's one device
+    the mesh step must give the unmeshed step's losses."""
     from repro.launch.mesh import host_mesh
     cfg = TINY.replace(pa=pa)
     _, plain = _run(tmp_path / "plain", cfg, steps=3)
