@@ -42,6 +42,19 @@ makes continuous output bit-match the one-shot engine per request.
 Inactive slots still flow through the lockstep decode (the batch shape is
 static): they are fed token 0 at position 0, write only their own free
 cache row, and their sampled output is discarded.
+
+Profiler spans (``jax.profiler.TraceAnnotation``, always on, about a
+microsecond each when no trace is active) put the tick's host phases on
+the device trace's clock: ``serve.tick`` (args ``tick``, ``active``,
+``admitted``) covers ``step``; inside it one ``serve.admit`` (``rid``,
+``prompt_len``) per admission with children ``.prepare`` (prefill batch
+and fresh batch-1 cache), ``.prefill`` (prefill and first-token
+dispatch), ``.first_token`` (the host fetch of the first token) and
+``.insert`` (the slot insert dispatch); then ``serve.decode.launch``
+(build the step's inputs, dispatch it), ``serve.decode.fetch`` (the host
+fetch of its outputs) and ``serve.emit`` (quarantine, bookkeeping,
+``on_token``, releases). The spans add no synchronisation: the fetches
+they name are the engine's own.
 """
 from __future__ import annotations
 
@@ -51,6 +64,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.models.registry import Model
 from .engine import (ServeConfig, cache_capacity_guard, make_prefill_batch,
@@ -79,18 +93,8 @@ class ContinuousEngine:
                  fault_plan=None):
         self.model, self.params, self.cfg = model, params, cfg
         self.fault_plan = fault_plan
-        self.scheduler = Scheduler(cfg.n_slots, max_queue=cfg.max_queue)
         self.cache = model.init_cache(cfg.n_slots, cfg.max_len)
-        self._tokens: Dict[int, List[int]] = {}
-        # flight recorder (cfg.record): running per-request digest — every
-        # emitted token id + its step's logits-row fingerprint folded in
-        self._digests: Dict[int, int] = {}
-        self.counters = _fresh_counters()
-        self._tainted_slots: set = set()
-        self.metrics = {
-            "ticks": 0, "prefills": 0, "occupancy": [],
-            "emit_wall": {}, "visible_wall": {}, "decode_wall": [],
-        }
+        self.reset()
         self._build()
 
     # -- jitted model surface ----------------------------------------------
@@ -180,14 +184,14 @@ class ContinuousEngine:
         and inactive rows are never read)."""
         self.scheduler = Scheduler(self.cfg.n_slots,
                                    max_queue=self.cfg.max_queue)
-        self._tokens = {}
-        self._digests = {}
+        self._tokens: Dict[int, List[int]] = {}
+        # flight recorder (cfg.record): running per-request digest — every
+        # emitted token id + its step's logits-row fingerprint folded in
+        self._digests: Dict[int, int] = {}
         self.counters = _fresh_counters()
-        self._tainted_slots = set()
-        self.metrics = {
-            "ticks": 0, "prefills": 0, "occupancy": [],
-            "emit_wall": {}, "visible_wall": {}, "decode_wall": [],
-        }
+        self._tainted_slots: set = set()
+        self.metrics = {"ticks": 0, "prefills": 0, "occupancy": [],
+                        "emit_wall": {}, "visible_wall": {}}
 
     # -- request intake ----------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -215,27 +219,34 @@ class ContinuousEngine:
     def _admit(self, slot: SlotState, req: Request,
                on_token: Optional[Callable]) -> None:
         sch = self.scheduler
-        batch = make_prefill_batch(self.model.cfg,
-                                   np.asarray(req.prompt, np.int32)[None])
-        one = self.model.init_cache(1, self.cfg.max_len)
-        logits, one = self._prefill_fn(self.params, batch, one)
-        if self.cfg.record:
-            from repro.resilience.recorder import (fold_token,
-                                                   request_digest_seed)
-            first, fdig = self._first_fn(logits, jnp.int32(req.rid))
-            first = int(first)
-            self._digests[req.rid] = fold_token(
-                request_digest_seed(req.rid), first, int(fdig))
-        else:
-            first = int(self._first_fn(logits, jnp.int32(req.rid)))
-        self.cache = self._insert_fn(self.cache, one,
-                                     np.int32(slot.index))
-        self.metrics["prefills"] += 1
-        sch.activate(slot, req, first)
-        self._tokens[req.rid] = [first]
-        self._emit(req.rid, first, on_token)
-        if sch.should_finish(slot, first, self.cfg.eos_id):
-            self._release(slot)
+        with TraceAnnotation("serve.admit", rid=req.rid,
+                             prompt_len=len(req.prompt)):
+            with TraceAnnotation("serve.admit.prepare"):
+                batch = make_prefill_batch(
+                    self.model.cfg, np.asarray(req.prompt, np.int32)[None])
+                one = self.model.init_cache(1, self.cfg.max_len)
+            with TraceAnnotation("serve.admit.prefill"):
+                logits, one = self._prefill_fn(self.params, batch, one)
+                picked = self._first_fn(logits, jnp.int32(req.rid))
+            with TraceAnnotation("serve.admit.first_token"):
+                if self.cfg.record:
+                    from repro.resilience.recorder import (
+                        fold_token, request_digest_seed)
+                    first, fdig = picked
+                    first = int(first)
+                    self._digests[req.rid] = fold_token(
+                        request_digest_seed(req.rid), first, int(fdig))
+                else:
+                    first = int(picked)
+            with TraceAnnotation("serve.admit.insert"):
+                self.cache = self._insert_fn(self.cache, one,
+                                             np.int32(slot.index))
+            self.metrics["prefills"] += 1
+            sch.activate(slot, req, first)
+            self._tokens[req.rid] = [first]
+            self._emit(req.rid, first, on_token)
+            if sch.should_finish(slot, first, self.cfg.eos_id):
+                self._release(slot)
 
     def _release(self, slot: SlotState, status: str = "ok") -> None:
         rid = slot.request.rid
@@ -273,35 +284,47 @@ class ContinuousEngine:
         """One scheduler tick: degrade (deadlines), admit, decode all
         active slots lockstep, quarantine non-finite slots, evict finished.
         Returns the number of tokens produced."""
+        sch = self.scheduler
+        with TraceAnnotation("serve.tick", tick=sch.tick) as span:
+            now = time.perf_counter()
+            for req in sch.pending:
+                if req.arrival <= sch.tick:
+                    self.metrics["visible_wall"].setdefault(req.rid, now)
+            self._degrade()
+            admissions = sch.admissions()
+            for slot, req in admissions:
+                self._admit(slot, req, on_token)
+
+            if self.fault_plan is not None:
+                spec = self.fault_plan.pop("poison_slot", sch.tick)
+                if spec is not None:
+                    from repro.resilience.faults import poison_cache_row
+                    target = next((s for s in sch.active_slots()
+                                   if s.request.rid == spec.rid), None)
+                    if target is not None:
+                        self.cache = poison_cache_row(self.model, self.cache,
+                                                      target.index)
+
+            active = sch.active_slots()
+            span.set_metadata(active=len(active), admitted=len(admissions))
+            produced = self._decode(active, on_token) if active else 0
+            self.metrics["occupancy"].append(len(active) / self.cfg.n_slots)
+            self.metrics["ticks"] += 1
+            sch.tick += 1
+        return produced
+
+    def _decode(self, active: List[SlotState],
+                on_token: Optional[Callable]) -> int:
+        """The tick's lockstep decode over ``active``, then quarantine,
+        bookkeeping and releases; returns the tokens emitted."""
         sch, cfg = self.scheduler, self.cfg
-        now = time.perf_counter()
-        for req in sch.pending:
-            if req.arrival <= sch.tick:
-                self.metrics["visible_wall"].setdefault(req.rid, now)
-        self._degrade()
-        for slot, req in sch.admissions():
-            self._admit(slot, req, on_token)
-
-        if self.fault_plan is not None:
-            spec = self.fault_plan.pop("poison_slot", sch.tick)
-            if spec is not None:
-                from repro.resilience.faults import poison_cache_row
-                target = next((s for s in sch.active_slots()
-                               if s.request.rid == spec.rid), None)
-                if target is not None:
-                    self.cache = poison_cache_row(self.model, self.cache,
-                                                  target.index)
-
-        active = sch.active_slots()
-        produced = 0
-        if active:
+        with TraceAnnotation("serve.decode.launch"):
             n = cfg.n_slots
             tok = np.zeros((n, 1), np.int32)
             pos = np.zeros((n,), np.int32)
             for s in active:
                 tok[s.index, 0] = s.last_token
                 pos[s.index] = s.next_pos
-            t0 = time.perf_counter()
             if cfg.temperature <= 0:
                 args = (self.params, self.cache, tok, pos)
             else:
@@ -314,10 +337,12 @@ class ContinuousEngine:
             outs = self._step_fn(*args)
             nxt, rest = outs[0], list(outs[1:-1])
             self.cache = outs[-1]
+        with TraceAnnotation("serve.decode.fetch"):
             bad = np.asarray(rest.pop(0)) if cfg.guard_nonfinite else None
             digs = np.asarray(rest.pop(0)) if cfg.record else None
             nxt = np.asarray(nxt)
-            self.metrics["decode_wall"].append(time.perf_counter() - t0)
+        produced = 0
+        with TraceAnnotation("serve.emit"):
             for s in active:
                 if bad is not None and bad[s.index]:
                     # quarantine: this slot's logits went non-finite — its
@@ -344,9 +369,6 @@ class ContinuousEngine:
                 produced += 1
                 if sch.should_finish(s, t, cfg.eos_id):
                     self._release(s)
-        self.metrics["occupancy"].append(len(active) / cfg.n_slots)
-        self.metrics["ticks"] += 1
-        sch.tick += 1
         return produced
 
     # -- drivers -----------------------------------------------------------
