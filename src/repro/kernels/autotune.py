@@ -26,8 +26,9 @@ from __future__ import annotations
 _DEFAULTS = {
     ("pam_matmul", "interpret"): (256, 256, 256, 16),
     # tpu tiles: untimed legal defaults. Blocks obey the (8, 128) rule;
-    # bk = 128 keeps the in-kernel contraction one 128-lane fori_loop and
-    # pads K = 576 to 640 rather than 1024.
+    # bk = 128 keeps each in-kernel contraction at 128 steps (one static
+    # chunk for tiles of up to 64 rows) and pads K = 576 to 640 rather
+    # than 1024.
     ("pam_matmul", "tpu"): (128, 128, 128, 8),
     ("pa_softmax", "interpret"): (8,),
     ("pa_softmax", "tpu"): (8,),

@@ -25,6 +25,7 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -151,15 +152,31 @@ def _palog2(a):
 # Two-level reduction: ``g`` steps accumulate into a group partial, and the
 # group partials add into the running sum in contraction order.
 #
-# Mosaic layout rules decide the loop form. A contraction whose length is
-# a multiple of 128 lanes runs as a ``fori_loop`` over groups: the A side is
-# rotated (``pltpu.roll``) so the group's first column sits in lane 0, and
-# the B side is stashed in scoped VMEM so each group loads its ``g`` rows
-# with one aligned dynamic sublane slice. Shorter or unaligned contractions
-# (the attention head dim, decode-sized tails, small test tiles) unroll
-# statically. Both forms add in the same order, so a tile product's bits do
-# not depend on which one ran.
+# The loop form follows from two static facts: the contraction length and
+# the rows a step writes. Steps run in static chunks, where every column
+# and row is a static slice and the scheduler overlaps the steps' lane
+# broadcasts and adds. A contraction that is not a multiple of 128 lanes
+# (the attention head dim, decode-sized tails, small test tiles) is one
+# chunk. An aligned one takes as many whole groups per chunk as keep
+# steps x vreg rows of a step's (M, N) partial within
+# ``_CHUNK_VREG_STEPS``: a TPU tile's 128 steps are one chunk up to 64
+# rows, and 128 rows take chunks of 64 steps, since longer static bodies
+# outgrow the scoped VMEM stack in the attention backward. Chunks of a
+# longer contraction run as a ``fori_loop``: the A side is rotated
+# (``pltpu.roll``) so the chunk's first column sits in lane 0, and the B
+# side waits in scoped VMEM so each chunk loads its rows with one aligned
+# dynamic sublane slice. An iteration is a serial chain that costs about
+# 20 single-vreg steps on a v5e, so short chunks are dear (DESIGN.md §2.1
+# has the measurements). The loop carry starts at -0.0, the identity of
+# IEEE addition, so every form adds the same values in the same order:
+# ``g`` steps into a group partial, group partials into the running sum in
+# contraction order. A tile product's bits do not depend on its form.
+# A chunk is traced once per block shape (``_chunk``), from lax primitives.
 # ---------------------------------------------------------------------------
+
+# Largest static chunk: steps x vreg rows (8 sublanes) of a step's partial.
+_CHUNK_VREG_STEPS = 1024
+
 
 def _contract(a_side, b_side, product, g):
     """Sum over the contraction axis of ``product(cols, rows)``.
@@ -168,38 +185,54 @@ def _contract(a_side, b_side, product, g):
     arrays; ``product`` maps the step's (M, 1) columns and (1, N) rows to
     an (M, N) f32 partial. ``g`` must divide C.
     """
-    c_len = a_side[0].shape[1]
+    m, c_len = a_side[0].shape
     ng = c_len // g
+    u = ng if c_len % 128 else _largest_divisor(
+        ng, _CHUNK_VREG_STEPS // (g * -(-m // 8)))      # groups per chunk
+    s = u * g
 
-    def group(cols, rows, base):
-        part = None
-        for j in range(base, base + g):
-            p = product(tuple(x[:, j:j + 1] for x in cols),
-                        tuple(y[j:j + 1, :] for y in rows))
-            part = p if part is None else part + p
-        return part
-
-    if c_len % 128 or ng < 2:
-        acc = None
-        for q in range(ng):
-            part = group(a_side, b_side, q * g)
-            acc = part if acc is None else acc + part
-        return acc
+    if s == c_len:
+        return _chunk(product, u, g)(a_side, b_side)
 
     def run(*scratch):
         for ref, y in zip(scratch, b_side):
             ref[...] = y
 
-        def body(q, acc):
-            off = pl.multiple_of(q * g, g)
-            cols = tuple(pltpu.roll(x, c_len - off, 1) for x in a_side)
-            rows = tuple(ref[pl.ds(off, g), :] for ref in scratch)
-            return acc + group(cols, rows, 0)
+        def body(c, acc):
+            off = pl.multiple_of(c * s, s)
+            cols = tuple(pltpu.roll(x, (c_len - off) % c_len, 1)
+                         for x in a_side)
+            rows = tuple(ref[pl.ds(off, s), :] for ref in scratch)
+            return _chunk(product, u, g)(cols, rows, acc)
 
-        return jax.lax.fori_loop(1, ng, body, group(a_side, b_side, 0))
+        neg0 = jnp.full((m, b_side[0].shape[1]), -0.0, jnp.float32)
+        return jax.lax.fori_loop(0, c_len // s, body, neg0)
 
     return pl.run_scoped(run, *[pltpu.VMEM(y.shape, y.dtype)
                                 for y in b_side])
+
+
+@functools.lru_cache(maxsize=None)
+def _chunk(product, u, g):
+    """``u`` groups of ``g`` static steps over the first ``u * g`` columns
+    of the A side and rows of the B side, added to ``acc`` when given.
+    Jitted, so that every kernel and problem shape with the same block
+    shapes shares one trace: the compilation cache keeps no traces, and a
+    static chunk is thousands of operations."""
+    def chunk(cols, rows, acc=None):
+        for q in range(u):
+            part = None
+            for j in range(q * g, (q + 1) * g):
+                p = product(
+                    tuple(lax.slice_in_dim(x, j, j + 1, axis=1)
+                          for x in cols),
+                    tuple(lax.slice_in_dim(y, j, j + 1, axis=0)
+                          for y in rows))
+                part = p if part is None else lax.add(part, p)
+            acc = part if acc is None else lax.add(acc, part)
+        return acc
+
+    return jax.jit(chunk)
 
 
 def _largest_divisor(n, g):
@@ -233,14 +266,21 @@ def _make_pam_dot(fmt, fold):
         return (xi & fmt.EXP_MASK) == ZERO
 
     def product(cols, rows):
-        sa, am = cols
-        sb, bmg, bzm = rows
-        mag = am + bmg
-        mag = jnp.where(mag < MINN, ZERO, jnp.minimum(mag, MAXF)) & bzm
-        bits = ((sa ^ sb) | mag).astype(jnp.int32)
+        # lax ops, not jnp: each jnp op is a nested jit trace, and a static
+        # chunk traces this once per step.
+        sa, am = (lax.broadcast_in_dim(x, (x.shape[0], rows[0].shape[1]),
+                                       (0, 1)) for x in cols)
+        sb, bmg, bzm = (lax.broadcast_in_dim(y, sa.shape, (0, 1))
+                        for y in rows)
+        mag = lax.add(am, bmg)
+        mag = lax.select(lax.lt(mag, MINN), lax.full_like(mag, ZERO),
+                         lax.min(mag, MAXF))
+        mag = lax.bitwise_and(mag, bzm)
+        bits = lax.convert_element_type(
+            lax.bitwise_or(lax.bitwise_xor(sa, sb), mag), jnp.int32)
         if to_f32:
-            bits = bits << to_f32
-        return jax.lax.bitcast_convert_type(bits, jnp.float32)
+            bits = lax.shift_left(bits, to_f32)
+        return lax.bitcast_convert_type(bits, jnp.float32)
 
     def pam_dot(a, b, g):
         ai = _fb.bits(a, fmt)

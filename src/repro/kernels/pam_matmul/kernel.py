@@ -6,8 +6,9 @@ so the kernels run on the **VPU** (8x128 int lanes). Each grid step bitcasts
 its (bm, bk) / (bk, bn) tiles to int32 once and contracts them as a sum of
 rank-1 steps — an A column broadcast across lanes against a B row broadcast
 across sublanes — with ``g`` steps accumulating in registers before each
-group partial joins the sum (``pa_prims._contract``: a ``fori_loop`` over
-128-lane-aligned contractions, a static unroll for short ones). No
+group partial joins the sum (``pa_prims._contract``: static chunks of
+steps, the whole 128-step contraction of a TPU tile where a step writes
+few rows, a ``fori_loop`` over shorter chunks for tall ones). No
 intermediate is larger than one (bm, bn) tile, so every kernel stays well
 inside the scoped VMEM limit.
 

@@ -56,13 +56,17 @@ def _compile(one_chip, fn, *shapes):
 
 
 @pytest.mark.parametrize("fmt", FMTS)
-@pytest.mark.parametrize("m,k,n", [(4096, 576, 1536), (4, 576, 49152)],
-                         ids=["train_mlp_up", "decode_lm_head"])
-def test_pam_matmul_fwd(one_chip, fmt, m, k, n):
+@pytest.mark.parametrize("batch,m,k,n", [(1, 4096, 576, 1536),
+                                          (1, 4, 576, 49152),
+                                          (1, 8, 576, 576),
+                                          (72, 1, 576, 64)],
+                         ids=["train_mlp_up", "decode_lm_head",
+                              "decode_proj_8_slots", "decode_slot_av"])
+def test_pam_matmul_fwd(one_chip, fmt, batch, m, k, n):
     bm, bn, bk, g = mm_kernel.tile_params(m, n, k, False, fmt)
     _compile(one_chip, lambda a, b: mm_kernel.pam_matmul_batched(
         a, b, bm=bm, bn=bn, bk=bk, g=g, interpret=False, fmt_name=fmt),
-        ((1, m, k), FMTS[fmt]), ((1, k, n), FMTS[fmt]))
+        ((batch, m, k), FMTS[fmt]), ((batch, k, n), FMTS[fmt]))
 
 
 @pytest.mark.parametrize("fmt", FMTS)
