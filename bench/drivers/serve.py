@@ -19,7 +19,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench import harness, program, trace, weights
+from bench import arch, harness, program, trace, weights
 from bench.reference import model as ref_model
 
 
@@ -76,11 +76,11 @@ class Program:
         conf, tr = run.config, run.traffic
         if not tr["greedy"]:
             raise ValueError("the serving check compares greedy tokens only")
-        self.sizes = program.sizes(conf)
+        self.sizes, self.ref = program.sizes(conf), arch.of(conf)
         self.model = program.build(conf)
         for p in tr["prompt_lengths"]:
             program.check_kv_block(conf, p, tr["max_len"])
-        self.params = weights.init_params(self.sizes, seed)
+        self.params = weights.init_params(self.ref, self.sizes, seed)
         program.check_params(self.model, self.params)
         self.engine = ContinuousEngine(self.model, self.params, ServeConfig(
             max_len=tr["max_len"], n_slots=tr["slots"], temperature=0.0))
@@ -113,7 +113,7 @@ class Program:
         self.engine.reset()
 
     def reseed(self, seed):
-        self.params = weights.init_params(self.sizes, seed)
+        self.params = weights.init_params(self.ref, self.sizes, seed)
         self.engine.params = self.params
         self.engine.reset()
 
@@ -203,9 +203,9 @@ def sample(seed, rec, sched, n):
 
 
 @functools.lru_cache(maxsize=None)
-def _forward(sizes_items):
+def _forward(ref, sizes_items):
     s = dict(sizes_items)
-    return jax.jit(lambda p, t, st, n: ref_model.logits(n, s, p, t, st),
+    return jax.jit(lambda p, t, st, n: ref.logits(n, s, p, t, st),
                    static_argnums=3)
 
 
@@ -213,7 +213,7 @@ def reference_gaps(run, seed, reqs, toks, served, lower=False):
     """For each checked request: the reference's logits over its prompt
     and served tokens, and each served token's gap below the best (with
     ``lower``: the gap of the token the control puts first)."""
-    s, tr = program.sizes(run.config), run.traffic
+    s, tr, ref = program.sizes(run.config), run.traffic, arch.of(run.config)
     L = tr["max_len"]
     rows, stream, spans = [], [], []
     for r in reqs:
@@ -222,10 +222,10 @@ def reference_gaps(run, seed, reqs, toks, served, lower=False):
         rows.append(np.pad(seq, (0, L - len(seq))))
         stream.append(np.arange(L) < len(p))
         spans.append((len(p) - 1, len(out)))
-    params = weights.init_params(s, seed)
+    params = weights.init_params(ref, s, seed)
     nx = ref_model.Numerics(mode=s["mode"], act=s["compute_dtype"],
                             kv_block=s["kv_block"] or 128)
-    fwd = _forward(tuple(sorted(s.items())))
+    fwd = _forward(ref, tuple(sorted(s.items())))
     tok_a, st_a = jnp.asarray(np.stack(rows)), jnp.asarray(np.stack(stream))
     lg = np.asarray(fwd(params, tok_a, st_a, nx).astype(jnp.float32))
     pick = None

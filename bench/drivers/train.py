@@ -17,7 +17,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench import correct, harness, program, trace, weights
+from bench import arch, correct, harness, program, trace, weights
 from bench.reference import model as ref_model, train as ref_train
 
 
@@ -42,7 +42,7 @@ class Program:
         from repro.optim import init_opt_state
         from repro.train import TrainConfig, jit_train_step
         self.run, conf, tr = run, run.config, run.traffic
-        self.sizes = program.sizes(conf)
+        self.sizes, self.ref = program.sizes(conf), arch.of(conf)
         self.model = program.build(conf)
         program.check_kv_block(conf, tr["seq"], tr["seq"])
         opt = opt_config(tr)
@@ -56,7 +56,7 @@ class Program:
     def start(self, seed):
         """Weights, state and batch pool for a seed; compiles once."""
         tr = self.run.traffic
-        self.params = weights.init_params(self.sizes, seed)
+        self.params = weights.init_params(self.ref, self.sizes, seed)
         program.check_params(self.model, self.params)
         self.opt_state = self._init_opt(self.params)
         self.pool = [jax.tree.map(jnp.asarray, b)
@@ -85,7 +85,7 @@ class Program:
             losses.append(self.step())
             if k == 0:
                 m1 = jax.device_get(self._norms(self.opt_state["m"]))
-        p0 = weights.init_params(self.sizes, seed)
+        p0 = weights.init_params(self.ref, self.sizes, seed)
         dn = jax.device_get(self._delta(self.params, p0))
         del p0
         return [float(x) for x in losses], m1, dn
@@ -121,15 +121,15 @@ def window(prog, seconds, in_flight, counter):
 def reference(run, seed, lower=False, **numerics):
     """The reference's readings for a seed: the same weights from the
     seed, the same first batches."""
-    s, tr = program.sizes(run.config), run.traffic
+    s, tr, ref = program.sizes(run.config), run.traffic, arch.of(run.config)
     nx = ref_model.Numerics(mode=s["mode"], act=s["compute_dtype"],
                             lower=lower, kv_block=s["kv_block"] or 128,
                             **numerics)
     pool = batches(seed, tr, s["vocab_size"])
     bs = [jax.tree.map(jnp.asarray, pool[i % len(pool)])
           for i in range(tr["checked_steps"])]
-    params = weights.init_params(s, seed)
-    return ref_train.run(nx, s, tr["optimizer"], params, bs)
+    params = weights.init_params(ref, s, seed)
+    return ref_train.run(ref, nx, s, tr["optimizer"], params, bs)
 
 
 def run_cell(run):

@@ -6,22 +6,21 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench import arch
+
 
 def sizes(conf: dict) -> dict:
-    """The reference's view of a configuration file."""
+    """The reference's view of a configuration file: its architecture's
+    sizes, and the numeric mode, dtypes and attention block that every
+    architecture shares."""
     prec = conf["precision"]
-    return {
-        "n_layers": conf["num_hidden_layers"], "d_model": conf["hidden_size"],
-        "n_heads": conf["num_attention_heads"],
-        "n_kv_heads": conf["num_key_value_heads"], "d_head": conf["head_dim"],
-        "d_ff": conf["intermediate_size"], "vocab_size": conf["vocab_size"],
-        "rope_theta": conf["rope_theta"], "rms_norm_eps": conf["rms_norm_eps"],
-        "tie_embeddings": conf["tie_word_embeddings"],
-        "param_dtype": prec["param_dtype"], "compute_dtype": prec["compute_dtype"],
-        "attn_fused_pam": conf["program"]["attn_fused_pam"],
-        "kv_block": prec.get("attention_kv_block"),
-        "mode": conf["program"]["mode"],
-    }
+    return dict(
+        arch.of(conf).sizes(conf),
+        param_dtype=prec["param_dtype"], compute_dtype=prec["compute_dtype"],
+        attn_fused_pam=conf["program"]["attn_fused_pam"],
+        kv_block=prec.get("attention_kv_block"),
+        mode=conf["program"]["mode"],
+    )
 
 
 def build(conf: dict):
@@ -35,19 +34,10 @@ def build(conf: dict):
     cfg = get_config(p["registry"], pa=pa, attn_fused_pam=p["attn_fused_pam"],
                      **p.get("overrides", {}))
     s = sizes(conf)
-    have = {
-        "n_layers": cfg.n_layers, "d_model": cfg.d_model,
-        "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-        "d_head": cfg.head_dim, "d_ff": cfg.d_ff,
-        "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
-        "tie_embeddings": cfg.tie_embeddings,
-        "param_dtype": str(jnp.dtype(cfg.param_dtype)),
-        "compute_dtype": str(jnp.dtype(cfg.cdtype)),
-    }
-    bad = {k: (v, s[k]) for k, v in have.items() if v != s[k]}
-    if (cfg.norm, cfg.activation, cfg.mlp_gated, cfg.family) != (
-            "rmsnorm", "silu", True, "decoder"):
-        bad["block"] = (cfg.norm, cfg.activation, cfg.mlp_gated, cfg.family)
+    bad = arch.of(conf).check(cfg, s)
+    have = {"param_dtype": str(jnp.dtype(cfg.param_dtype)),
+            "compute_dtype": str(jnp.dtype(cfg.cdtype))}
+    bad.update({k: (v, s[k]) for k, v in have.items() if v != s[k]})
     if bad:
         raise RuntimeError(f"program config departs from the file: {bad}")
     return build_model(cfg)
@@ -62,7 +52,7 @@ def check_kv_block(conf: dict, s_len: int, t_len: int) -> None:
     from repro.kernels import autotune
     from repro.kernels._backend import use_interpret
     got = autotune.tile_params("pam_attention", (s_len, t_len,
-                                                 conf["head_dim"]),
+                                                 sizes(conf)["d_head"]),
                                use_interpret())[1]
     if int(got) != int(kv):
         raise RuntimeError(f"fused attention streams KV blocks of {got}, "

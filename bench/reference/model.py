@@ -1,4 +1,4 @@
-"""Plain reference of a LLaMA-style decoder (SmolLM's architecture) under
+"""The plain reference's numerics, shared by every architecture, under
 the three numeric modes the benchmark's configurations state.
 
   off     native float ops in the configuration's compute dtype
@@ -7,8 +7,13 @@ the three numeric modes the benchmark's configurations state.
           attention as the fused streaming PA softmax over KV blocks of
           ``kv_block`` keys (the configuration states the block)
 
-Imports nothing of the program. Parameters are the benchmark's own
-(``bench/weights.py``), a nested dict of the published layout.
+Here: ``Numerics`` (products, RMSNorm, SiLU, RoPE, softmax and the
+cross-entropy in each mode) and causal attention, materialised or as the
+fused streaming PA softmax. An architecture's blocks, forward and loss
+live in its own module, ``bench/reference/<architecture>.py``, found by
+``bench/arch.py``. Imports nothing of the program. Parameters are the
+benchmark's own (``bench/weights.py``), a nested dict of the
+architecture's layout.
 
 The control of each configuration is this reference one precision lower:
 ``lower=True`` rounds every activation to bfloat16 (for the float32
@@ -276,44 +281,3 @@ def attention(nx: Numerics, q, k, v, qpos, kpos, stream_rows=None):
     if stream_rows is None or not nx.pa:
         return out
     return jnp.where(stream_rows[:, :, None, None], fused, out)
-
-
-# -- the decoder --------------------------------------------------------------
-
-def block(nx: Numerics, cfg, h, lp, positions, stream_rows):
-    b, s, _ = h.shape
-    hq, hkv, dh = cfg["n_heads"], cfg["n_kv_heads"], cfg["d_head"]
-    eps = cfg["rms_norm_eps"]
-    x = nx.rmsnorm(h, lp["attn_norm"]["scale"], eps)
-    q = nx.mm(x, lp["attn"]["wq"]).reshape(b, s, hq, dh)
-    k = nx.mm(x, lp["attn"]["wk"]).reshape(b, s, hkv, dh)
-    v = nx.mm(x, lp["attn"]["wv"]).reshape(b, s, hkv, dh)
-    q = nx.rope(q, positions, cfg["rope_theta"])
-    k = nx.rope(k, positions, cfg["rope_theta"])
-    a = attention(nx, q, k, v, positions, positions, stream_rows)
-    h = h + nx.mm(a.reshape(b, s, hq * dh), lp["attn"]["wo"])
-    x = nx.rmsnorm(h, lp["mlp_norm"]["scale"], eps)
-    up = nx.mm(x, lp["mlp"]["w_up"])
-    gate = nx.silu(nx.mm(x, lp["mlp"]["w_gate"]))
-    return h + nx.mm(nx.mul(up, gate), lp["mlp"]["w_down"])
-
-
-def logits(nx: Numerics, cfg, params, tokens, stream_rows=None):
-    """All-position logits of tokens (B, S); positions 0..S-1."""
-    s = tokens.shape[1]
-    positions = jnp.arange(s, dtype=jnp.int32)[None]
-    h = jnp.take(params["embed"], tokens, axis=0).astype(nx.dt)
-
-    @jax.checkpoint
-    def body(h, lp):
-        return block(nx, cfg, h, lp, positions, stream_rows), None
-
-    h, _ = jax.lax.scan(body, h, params["layers"])
-    h = nx.rmsnorm(h, params["final_norm"]["scale"], cfg["rms_norm_eps"])
-    return nx.mm(h, params["embed"].T)
-
-
-def loss(nx: Numerics, cfg, params, batch):
-    stream = "all" if nx.pa and cfg.get("attn_fused_pam") else None
-    lg = logits(nx, cfg, params, batch["tokens"], stream).astype(F32)
-    return nx.cross_entropy(lg, batch["labels"], batch["mask"])

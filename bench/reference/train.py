@@ -1,6 +1,7 @@
-"""Reference training: the loss and gradients of ``model.loss`` and an
-AdamW step, native or piecewise affine (paper section 2.6), written
-plainly; and the per-leaf readings the comparison takes."""
+"""Reference training: the loss and gradients of an architecture's
+``loss`` (its reference module, ``bench/arch.py``) and an AdamW step,
+native or piecewise affine (paper section 2.6), written plainly; and the
+per-leaf readings the comparison takes."""
 from __future__ import annotations
 
 import functools
@@ -91,21 +92,22 @@ def delta_norms(a, b):
 
 
 @functools.lru_cache(maxsize=None)
-def _step(nx: model.Numerics, cfg_items: tuple, opt_items: tuple):
+def _step(ref, nx: model.Numerics, cfg_items: tuple, opt_items: tuple):
     cfg, opt = dict(cfg_items), dict(opt_items)
     update = pa_adamw if nx.mode == "full" else adamw
 
     def step(p, s, b):
-        loss, g = jax.value_and_grad(lambda q: model.loss(nx, cfg, q, b))(p)
+        loss, g = jax.value_and_grad(lambda q: ref.loss(nx, cfg, q, b))(p)
         p, s = update(p, g, s, opt)
         return p, s, loss
     return jax.jit(step)
 
 
-def run(nx: model.Numerics, cfg: dict, opt: dict, params, batches):
-    """len(batches) steps from ``params``; returns (losses, first-step
-    moment norms per leaf, norms of the parameter change per leaf)."""
-    step = _step(nx, tuple(sorted(cfg.items())), tuple(sorted(opt.items())))
+def run(ref, nx: model.Numerics, cfg: dict, opt: dict, params, batches):
+    """len(batches) steps of the architecture ``ref`` from ``params``;
+    returns (losses, first-step moment norms per leaf, norms of the
+    parameter change per leaf)."""
+    step = _step(ref, nx, tuple(sorted(cfg.items())), tuple(sorted(opt.items())))
 
     state = {"m": jax.tree.map(lambda x: jnp.zeros(x.shape, F32), params),
              "v": jax.tree.map(lambda x: jnp.zeros(x.shape, F32), params),

@@ -1,9 +1,11 @@
-"""Work counts and the end-to-end arithmetic: FLOPs per token, bytes from
-shapes, a rate over whole steps, and tails over all requests."""
+"""Work counts and the end-to-end arithmetic: FLOPs per token (of the
+LLaMA architecture), bytes from shapes, a rate over whole steps, and
+tails over all requests."""
 import numpy as np
 
 from bench import work
 from bench.drivers import serve, train
+from bench.reference import llama
 
 
 def test_flops_per_token_smollm():
@@ -12,8 +14,8 @@ def test_flops_per_token_smollm():
     per_layer = 576 * 576 + 2 * 576 * 192 + 576 * 576 + 3 * 576 * 1536
     dense = 30 * per_layer + 576 * 49152
     attn = 30 * 2 * 576 * 512.5
-    assert work.flops_per_token(s, 1024) == 6 * (dense + attn)
-    assert 0.9e9 < work.flops_per_token(s, 1024) < 0.95e9
+    assert llama.flops_per_token(s, 1024) == 6 * (dense + attn)
+    assert 0.9e9 < llama.flops_per_token(s, 1024) < 0.95e9
 
 
 def test_shape_bytes():

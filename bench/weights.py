@@ -2,12 +2,9 @@
 
 The benchmark makes the parameters itself, on the device, in one jitted
 call, so the program under test and the reference see the same numbers
-and neither makes them. The layout is the published LLaMA-style decoder:
-token embedding (tied output head), per layer an RMSNorm scale, the
-q/k/v/o projections, a second RMSNorm scale and the gated MLP, stacked
-along a leading layer axis, then the final RMSNorm scale. A projection
-of fan-in n is normal with standard deviation 1/sqrt(n), the embedding
-0.02, the norm scales one.
+and neither makes them. The layout, each leaf's shape and standard
+deviation, is the architecture's (``layout`` of its reference module,
+``bench/arch.py``); each leaf is normal with that deviation, or ones.
 """
 from __future__ import annotations
 
@@ -23,28 +20,6 @@ def key_words(seed: int, stream: str) -> np.ndarray:
     beyond 32 bits, with independent streams per use."""
     tag = int.from_bytes(stream.encode(), "little") % (1 << 63)
     return np.random.SeedSequence([int(seed), tag]).generate_state(2)
-
-
-def layout(cfg: dict) -> dict:
-    """{leaf path: (shape, init std or 'ones')} of the parameter tree."""
-    L, d, v = cfg["n_layers"], cfg["d_model"], cfg["vocab_size"]
-    q, kv, f = cfg["n_heads"] * cfg["d_head"], cfg["n_kv_heads"] * cfg["d_head"], cfg["d_ff"]
-    lay = {
-        ("embed",): ((v, d), 0.02),
-        ("final_norm", "scale"): ((d,), "ones"),
-        ("layers", "attn_norm", "scale"): ((L, d), "ones"),
-        ("layers", "mlp_norm", "scale"): ((L, d), "ones"),
-        ("layers", "attn", "wq"): ((L, d, q), d ** -0.5),
-        ("layers", "attn", "wk"): ((L, d, kv), d ** -0.5),
-        ("layers", "attn", "wv"): ((L, d, kv), d ** -0.5),
-        ("layers", "attn", "wo"): ((L, q, d), q ** -0.5),
-        ("layers", "mlp", "w_up"): ((L, d, f), d ** -0.5),
-        ("layers", "mlp", "w_gate"): ((L, d, f), d ** -0.5),
-        ("layers", "mlp", "w_down"): ((L, f, d), f ** -0.5),
-    }
-    if not cfg.get("tie_embeddings", True):
-        raise ValueError("only tied embeddings are laid out")
-    return lay
 
 
 def _nest(flat: dict) -> dict:
@@ -73,9 +48,11 @@ def _init_fn(items: tuple, dtype: str):
     return jax.jit(init)
 
 
-def init_params(cfg: dict, seed: int):
-    """The parameter tree for (cfg, seed), made on the default device."""
-    items = tuple((p, s, std) for p, (s, std) in sorted(layout(cfg).items()))
+def init_params(ref, cfg: dict, seed: int):
+    """The parameter tree for (cfg, seed) in the layout of the
+    architecture's reference module ``ref`` (``bench/arch.py``), made on
+    the default device."""
+    items = tuple((p, s, std) for p, (s, std) in sorted(ref.layout(cfg).items()))
     words = jnp.asarray(key_words(seed, "weights"), jnp.uint32)
     return _init_fn(items, cfg["param_dtype"])(words)
 
